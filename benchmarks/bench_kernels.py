@@ -43,9 +43,9 @@ def run() -> None:
     A = -jnp.exp(0.3 * jax.random.normal(ks[2], (nh,)))
     B_ = 0.3 * jax.random.normal(ks[3], (B, S, N))
     C_ = 0.3 * jax.random.normal(ks[4], (B, S, N))
-    us = _bench(lambda a: ops.ssd(a, dt, A, B_, C_, chunk=128, nh_block=4), x)
-    err = float(np.max(np.abs(np.asarray(ops.ssd(x, dt, A, B_, C_, chunk=128,
-                                                 nh_block=4)) -
+    us = _bench(lambda a: ops.ssd(a, dt, A, B_, C_, chunk=128), x)
+    err = float(np.max(np.abs(np.asarray(ops.ssd(x, dt, A, B_, C_,
+                                                 chunk=128)) -
                               np.asarray(ref.ssd_ref(x, dt, A, B_, C_)))))
     emit("kernel/ssd_scan/B2S256nh8", us, f"max_abs_err={err:.2e}")
 

@@ -6,7 +6,7 @@ Rows (CSV: name,us_per_call,derived):
   serve/corun.aggregate    both tenants' tokens over the co-run wall time
   serve/offload.<arch>     tenant under a forced offload plan (spill path)
 
-Wall times on the CPU container measure *engine overhead*, not TPU step
+Wall times on the CPU backend measure *engine overhead*, not TPU step
 time; the modeled throttle/energy figures come from core.power and are
 printed in the derived column.
 """

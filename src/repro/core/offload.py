@@ -11,9 +11,10 @@ tables (one row gather per token), cold KV-cache tails — and only then
 activations.
 
 Plans are applied with real JAX memory kinds: ``NamedSharding(mesh, spec,
-memory_kind="pinned_host")`` placements for spilled tensors (works on the CPU
-backend of this container, and on real TPU runtimes), plus the
-``remat="offload"`` activation policy in the model zoo.
+memory_kind="pinned_host")`` placements for spilled tensors, plus the
+``remat="offload"`` activation policy in the model zoo. A spilled leaf is
+never an operand of device computation: ``fetch_to_device`` copies it in
+explicitly for each step (JAX refuses computation across memory spaces).
 """
 from __future__ import annotations
 
@@ -359,27 +360,43 @@ def inventory_from_tree(tree: PyTree, *, default_group: Optional[str] = None
 # ---------------------------------------------------------------------------
 # plan application (real memory kinds)
 # ---------------------------------------------------------------------------
-def _memory_kind(mesh, preferred: str) -> str:
-    import jax as _jax
-    dev = (mesh.devices.flat[0] if mesh is not None else _jax.devices()[0])
+def _memory_kind(mesh, kind: str) -> str:
+    dev = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
     kinds = {m.kind for m in dev.addressable_memories()}
-    return preferred if preferred in kinds else dev.default_memory().kind
+    if kind not in kinds:
+        raise ValueError(f"{dev.platform} device {dev} has no {kind!r} memory "
+                         f"space (it has {sorted(kinds)}); the offload tier "
+                         f"cannot be placed")
+    return kind
 
 
 def host_memory_kind(mesh=None) -> str:
-    """The host-tier memory kind this backend can address.
-
-    ``pinned_host`` on runtimes that expose it (TPU, GPU); the CPU backend
-    of the test container has a single ``unpinned_host`` space, so both
-    tiers resolve to the same kind there — the spill is physically a no-op
-    but every plan/placement code path still executes.
-    """
+    """The host-tier memory kind: ``pinned_host``. TPU, GPU and the CPU
+    backend all expose it; a backend without it is an error, not a silent
+    fall-back to device memory."""
     return _memory_kind(mesh, "pinned_host")
 
 
 def device_memory_kind(mesh=None) -> str:
-    """The device-tier (HBM) memory kind — ``device`` where it exists."""
+    """The device-tier (HBM) memory kind: ``device``."""
     return _memory_kind(mesh, "device")
+
+
+def in_host_memory(x) -> bool:
+    """True when ``x`` is an array held in a host memory space."""
+    kind = getattr(getattr(x, "sharding", None), "memory_kind", None)
+    return kind in ("pinned_host", "unpinned_host")
+
+
+def fetch_to_device(tree: PyTree) -> PyTree:
+    """Explicit host→device transfer of every leaf held in host memory.
+
+    JAX computes only on operands in one memory space, so a spilled leaf
+    enters a step through this copy; the host-resident original stays where
+    the plan put it and the device copy is dropped after the step."""
+    return jax.tree_util.tree_map(
+        lambda x: (jax.device_put(x, x.sharding.with_memory_kind("device"))
+                   if in_host_memory(x) else x), tree)
 
 
 def shardings_with_offload(spec_tree: PyTree, plan: OffloadPlan, mesh, *,

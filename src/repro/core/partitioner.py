@@ -456,6 +456,7 @@ class StaticPartitioner:
         Note: this moves *logical* rectangles; a real runtime would migrate
         the tenant's state between the old and new device sets.
         """
+        before = self.largest_free_profile()
         old_grid = self._grid.copy()
         dead = self._grid == -2
         self._grid = np.full_like(self._grid, -1)
@@ -476,6 +477,13 @@ class StaticPartitioner:
             self._grid[r:r + alloc.profile.rows, c:c + alloc.profile.cols] = sid
             self._gen += 1
             placed[sid] = origin
+        after = self.largest_free_profile()
+        if (after.n_chips if after else 0) < (before.n_chips if before else 0):
+            # largest-first first-fit can strand more than the layout it
+            # replaces; keep the old layout then, nothing was moved
+            self._grid = old_grid
+            self._gen += 1
+            return {}
         moved: Dict[int, Tuple[int, int]] = {}
         for sid, origin in placed.items():
             alloc = self.allocations[sid]

@@ -59,12 +59,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(rows >= cols, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                                  # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1)
-        acc_scr[...] = (acc_scr[...] * corr[:, None] +
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = (acc_scr[...] * corr +
                         jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                             preferred_element_type=jnp.float32))
         m_scr[...] = m_new
@@ -72,14 +72,24 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ik == kv_blocks - 1)
     def _finish():
         o_ref[0] = (acc_scr[...] /
-                    jnp.maximum(l_scr[...], 1e-30)[:, None]).astype(o_ref.dtype)
+                    jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _stats_scratch(block_q: int, hd: int):
+    """VMEM scratch: running max and sum as (block_q, 1) columns, and the
+    fp32 output accumulator."""
+    return [pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, hd), jnp.float32)]
 
 
 def flash_attention_fwd_stats(q, k, v, *, causal: bool = True, scale=None,
                               block_q: int = 128, block_k: int = 128,
                               interpret: bool = False):
     """Forward + logsumexp stats (for the backward kernel).
-    Returns (out (BH,S,hd), lse (BH,S))."""
+    Returns (out (BH,S,hd), lse (BH,S,1)). The stats keep a trailing unit
+    axis so each block is a (block_q, 1) column, a shape the TPU tiling
+    accepts (a (1, block_q) row block of a (BH, S) array it refuses)."""
     BH, S, hd = q.shape
     Sk = k.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
@@ -100,17 +110,13 @@ def flash_attention_fwd_stats(q, k, v, *, causal: bool = True, scale=None,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, hd), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, iq, ik: (bh, iq)),
+            pl.BlockSpec((1, block_q, 1), lambda bh, iq, ik: (bh, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, S, hd), q.dtype),
-            jax.ShapeDtypeStruct((BH, S), jnp.float32),
+            jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, hd), jnp.float32),
-        ],
+        scratch_shapes=_stats_scratch(block_q, hd),
         interpret=interpret,
     )(q, k, v)
 
@@ -155,8 +161,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0].astype(jnp.float32)                  # (bk, hd)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)                # (bq, hd)
-        lse = lse_ref[0]                                  # (bq,)
-        delta = delta_ref[0]                              # (bq,)
+        lse = lse_ref[0]                                  # (bq, 1)
+        delta = delta_ref[0]                              # (bq, 1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if causal:
@@ -165,12 +171,12 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             cols = k_start + jax.lax.broadcasted_iota(jnp.int32,
                                                       (block_q, block_k), 1)
             s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                     # (bq, bk)
+        p = jnp.exp(s - lse)                              # (bq, bk)
         dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])                    # (bq, bk)
+        ds = p * (dp - delta)                             # (bq, bk)
         dk_scr[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
 
@@ -210,10 +216,10 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             cols = k_start + jax.lax.broadcasted_iota(jnp.int32,
                                                       (block_q, block_k), 1)
             s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         dq_scr[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
 
@@ -225,8 +231,8 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def flash_attention_bwd(q, k, v, out, lse, dout, *, causal: bool = True,
                         scale=None, block_q: int = 128, block_k: int = 128,
                         interpret: bool = False):
-    """Flash backward: (dq, dk, dv), each (BH, S, hd). ``lse`` from
-    flash_attention_fwd_stats. Two pallas_calls: dk/dv with the q dim
+    """Flash backward: (dq, dk, dv), each (BH, S, hd). ``lse`` (BH, S, 1)
+    from flash_attention_fwd_stats. Two pallas_calls: dk/dv with the q dim
     innermost (accumulated in VMEM), dq with the kv dim innermost."""
     BH, S, hd = q.shape
     Sk = k.shape[1]
@@ -234,7 +240,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal: bool = True,
     block_q = min(block_q, S)
     block_k = min(block_k, Sk)
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)  # (BH, S)
+                    axis=-1, keepdims=True)  # (BH, S, 1)
 
     kv_kernel = functools.partial(
         _flash_bwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
@@ -247,8 +253,8 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal: bool = True,
             pl.BlockSpec((1, block_k, hd), lambda bh, ik, iq: (bh, ik, 0)),
             pl.BlockSpec((1, block_k, hd), lambda bh, ik, iq: (bh, ik, 0)),
             pl.BlockSpec((1, block_q, hd), lambda bh, ik, iq: (bh, iq, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, ik, iq: (bh, iq)),
-            pl.BlockSpec((1, block_q), lambda bh, ik, iq: (bh, iq)),
+            pl.BlockSpec((1, block_q, 1), lambda bh, ik, iq: (bh, iq, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda bh, ik, iq: (bh, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, hd), lambda bh, ik, iq: (bh, ik, 0)),
@@ -272,8 +278,8 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal: bool = True,
             pl.BlockSpec((1, block_k, hd), lambda bh, iq, ik: (bh, ik, 0)),
             pl.BlockSpec((1, block_k, hd), lambda bh, iq, ik: (bh, ik, 0)),
             pl.BlockSpec((1, block_q, hd), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, iq, ik: (bh, iq)),
-            pl.BlockSpec((1, block_q), lambda bh, iq, ik: (bh, iq)),
+            pl.BlockSpec((1, block_q, 1), lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda bh, iq, ik: (bh, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, hd), lambda bh, iq, ik: (bh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), q.dtype),
@@ -309,10 +315,6 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, scale=None,
         ],
         out_specs=pl.BlockSpec((1, block_q, hd), lambda bh, iq, ik: (bh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, hd), jnp.float32),
-        ],
+        scratch_shapes=_stats_scratch(block_q, hd),
         interpret=interpret,
     )(q, k, v)

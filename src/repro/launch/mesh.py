@@ -5,20 +5,12 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: ``AxisType`` (and the
-    ``axis_types`` kwarg) only exist in newer releases; older ones default
-    to auto axes anyway."""
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis in Auto mode (sharding propagated
+    by the compiler from the specs the model states)."""
     import jax
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-
-
-_make_mesh = make_mesh_compat
+    from jax.sharding import AxisType
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,7 +18,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     (2 pods = 512 chips). Parameters never shard over "pod" (DESIGN.md §5)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_slice_mesh(devices_2d, axis_names: Tuple[str, str] = ("data", "model")):
@@ -36,7 +28,8 @@ def make_slice_mesh(devices_2d, axis_names: Tuple[str, str] = ("data", "model"))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over host (CPU) devices for tests/examples."""
+    """``(data, model)`` mesh over the first ``data * model`` local devices:
+    the chips of one host, or CPU devices in tests."""
     import jax
     n = data * model
     avail = len(jax.devices())
@@ -45,4 +38,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
             f"need {n} devices, have {avail}; set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n} before "
             f"importing jax")
-    return _make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

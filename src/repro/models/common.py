@@ -74,6 +74,9 @@ class AxisEnv:
     def size(self, axis: str) -> int:
         return self.axis_sizes[axis]
 
+    def __hash__(self) -> int:   # a Model is a static argument of jit
+        return hash((self.mesh_axes, tuple(sorted(self.axis_sizes.items()))))
+
     @staticmethod
     def from_mesh(mesh) -> "AxisEnv":
         return AxisEnv(tuple(mesh.axis_names),

@@ -90,17 +90,9 @@ def cross_pod_sync(grads: PyTree, err: PyTree, mesh, *, compress: bool = True
         return td.unflatten([o[0] for o in out]), td.unflatten([o[1] for o in out])
 
     spec = P()  # replicated over pod inside; data/model stay automatic
-    try:
-        fn = jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec),
-                           out_specs=(spec, spec), axis_names={"pod"},
-                           check_vma=False)
-    except AttributeError:
-        # older jax: experimental shard_map (check_vma named check_rep).
-        # With replicated in/out specs full-manual mode is equivalent to
-        # manual-over-"pod"; partial-auto crashes old XLA's partitioner.
-        from jax.experimental.shard_map import shard_map as _shard_map
-        fn = _shard_map(inner, mesh=mesh, in_specs=(spec, spec),
-                        out_specs=(spec, spec), check_rep=False)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec),
+                       out_specs=(spec, spec), axis_names={"pod"},
+                       check_vma=False)
     return fn(grads, err)
 
 

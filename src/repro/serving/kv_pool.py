@@ -12,11 +12,13 @@ memory kinds —
   single JAX buffer has exactly one memory kind);
 * everything else stays in device memory.
 
-Decode consumes ``materialize()`` (tail concatenated back on) and returns
-the updated tree to ``update()``, which re-splits and re-pins the tail —
-the double-buffered DMA round-trip of DESIGN.md §2, executed eagerly here.
-On this CPU container both tiers are host RAM, so the split costs nothing
-and changes nothing numerically; the roofline model prices the real link.
+JAX computes only on operands in one memory space, so a host-resident leaf
+never enters computation directly. ``materialize()`` copies every spilled
+tail and leaf to device memory explicitly and concatenates the tails back
+on; decode returns the updated tree to ``update()``, which re-splits it and
+writes the spilled bytes back to ``pinned_host``. Between steps the spilled
+bytes stay in host memory. The round trip copies every spilled byte twice
+per step; this is the correct form, not yet the fast one.
 """
 from __future__ import annotations
 
@@ -38,6 +40,12 @@ SEQ_AXIS = 2  # layer-stacked caches: (L, slots, seq, heads, head_dim)
 
 def _has_seq_axis(leaf, max_seq: int) -> bool:
     return leaf.ndim > SEQ_AXIS and leaf.shape[SEQ_AXIS] == max_seq
+
+
+def _seq_slice(leaf, lo: int, hi: int):
+    idx = [slice(None)] * leaf.ndim
+    idx[SEQ_AXIS] = slice(lo, hi)
+    return leaf[tuple(idx)]
 
 
 def _spec_allows_seq_split(spec, mesh) -> bool:
@@ -79,9 +87,8 @@ class KVPool:
         spec_by_path = dict(specs)
 
         # per-leaf placement decision
-        self._hot_sharding: Dict[int, NamedSharding] = {}
-        self._cold_sharding: Dict[int, NamedSharding] = {}
-        self._host_sharding: Dict[int, NamedSharding] = {}   # fully-host
+        self._dev_sharding: Dict[int, NamedSharding] = {}    # whole leaf
+        self._host_sharding: Dict[int, NamedSharding] = {}   # spilled part
         self._hot_len: Dict[int, int] = {}            # split leaves only
         self._hot: List[Any] = []
         self._cold: Dict[int, Any] = {}
@@ -93,26 +100,24 @@ class KVPool:
             full_path = f"{prefix}/{path}" if prefix else path
             kind, hot_len = self._decide(full_path, leaf, plan, offload_all,
                                          spec_by_path.get(path))
-            if mesh is not None and kind != "device":
+            if mesh is not None:
                 spec = spec_by_path.get(path)
+                self._dev_sharding[i] = NamedSharding(mesh, spec,
+                                                      memory_kind=dev_kind)
+                if kind != "device":
+                    self._host_sharding[i] = NamedSharding(
+                        mesh, spec, memory_kind=host_kind)
                 if kind == "host":
-                    sh = NamedSharding(mesh, spec, memory_kind=host_kind)
-                    leaf = jax.device_put(leaf, sh)
+                    leaf = jax.device_put(leaf, self._host_sharding[i])
                     self._host_leaves.add(i)
-                    self._host_sharding[i] = sh
                 elif kind == "split":
-                    hot_sh = NamedSharding(mesh, spec, memory_kind=dev_kind)
-                    cold_sh = NamedSharding(mesh, spec,
-                                            memory_kind=host_kind)
-                    idx = [slice(None)] * leaf.ndim
-                    idx[SEQ_AXIS] = slice(0, hot_len)
-                    hot = jax.device_put(leaf[tuple(idx)], hot_sh)
-                    idx[SEQ_AXIS] = slice(hot_len, max_seq)
-                    self._cold[i] = jax.device_put(leaf[tuple(idx)], cold_sh)
+                    self._cold[i] = jax.device_put(
+                        _seq_slice(leaf, hot_len, max_seq),
+                        self._host_sharding[i])
                     self._hot_len[i] = hot_len
-                    self._hot_sharding[i] = hot_sh
-                    self._cold_sharding[i] = cold_sh
-                    leaf = hot
+                    leaf = _seq_slice(leaf, 0, hot_len)
+                if kind != "host":
+                    leaf = jax.device_put(leaf, self._dev_sharding[i])
             self._hot.append(leaf)
 
     # ------------------------------------------------------------------
@@ -153,40 +158,47 @@ class KVPool:
     # cache access
     # ------------------------------------------------------------------
     def materialize(self) -> PyTree:
-        """Full cache tree for decode: cold tails concatenated back on."""
-        if not self._cold:
-            return jax.tree_util.tree_unflatten(self._treedef, self._hot)
+        """Full cache tree in device memory for one step: spilled leaves and
+        cold tails are copied in explicitly, tails concatenated back on."""
         leaves = []
-        for i, hot in enumerate(self._hot):
-            if i in self._cold:
-                leaves.append(jnp.concatenate([hot, self._cold[i]],
-                                              axis=SEQ_AXIS))
-            else:
-                leaves.append(hot)
+        for i, leaf in enumerate(self._hot):
+            if i in self._host_leaves:
+                leaf = jax.device_put(leaf, self._dev_sharding[i])
+            elif i in self._cold:
+                cold = jax.device_put(self._cold[i], self._dev_sharding[i])
+                leaf = jax.device_put(
+                    jnp.concatenate([leaf, cold], axis=SEQ_AXIS),
+                    self._dev_sharding[i])
+            leaves.append(leaf)
         return jax.tree_util.tree_unflatten(self._treedef, leaves)
 
     def update(self, new_cache: PyTree) -> None:
-        """Absorb a decode-updated cache tree, re-splitting spilled tails
-        back into pinned_host (the write-back half of the DMA round trip)."""
+        """Absorb a step-updated cache tree (device memory), re-splitting
+        spilled tails and writing every spilled byte back to the host tier
+        (the write-back half of the DMA round trip)."""
         leaves = jax.tree_util.tree_leaves(new_cache)
         assert len(leaves) == len(self._hot), "cache structure changed"
         for i, leaf in enumerate(leaves):
             if i in self._cold:
                 hot_len = self._hot_len[i]
-                idx = [slice(None)] * leaf.ndim
-                idx[SEQ_AXIS] = slice(0, hot_len)
-                self._hot[i] = jax.device_put(leaf[tuple(idx)],
-                                              self._hot_sharding[i])
-                idx[SEQ_AXIS] = slice(hot_len, self.max_seq)
-                self._cold[i] = jax.device_put(leaf[tuple(idx)],
-                                               self._cold_sharding[i])
+                self._hot[i] = jax.device_put(_seq_slice(leaf, 0, hot_len),
+                                              self._dev_sharding[i])
+                self._cold[i] = jax.device_put(
+                    _seq_slice(leaf, hot_len, self.max_seq),
+                    self._host_sharding[i])
             elif i in self._host_leaves:
-                # eager decode outputs land in device memory; pin the leaf
-                # back to the host tier or the whole "offloaded" pool would
-                # migrate to HBM after one tick
                 self._hot[i] = jax.device_put(leaf, self._host_sharding[i])
+            elif self.mesh is not None:
+                # keep the pool's layout fixed so the step never recompiles
+                self._hot[i] = jax.device_put(leaf, self._dev_sharding[i])
             else:
                 self._hot[i] = leaf
+        # Every step rewrites the whole pool (and a paste, one per admitted
+        # request, does too). Left asynchronous, the host queues the next
+        # rewrite while earlier pools and their host copies are still
+        # alive: on a 16 GB chip that stacked ~3.4 GB of pool copies.
+        # Waiting holds it to one old and one new pool.
+        jax.block_until_ready((self._hot, list(self._cold.values())))
 
     def paste(self, slot: int, prefix_cache: PyTree, plen: int) -> None:
         """Write a prefill prefix into one slot (the admit path)."""
@@ -205,14 +217,13 @@ class KVPool:
     # ------------------------------------------------------------------
     # introspection
     # ------------------------------------------------------------------
-    def memory_kinds(self) -> Set[str]:
-        kinds = set()
-        for i, leaf in enumerate(self._hot):
-            sh = getattr(leaf, "sharding", None)
-            kinds.add(getattr(sh, "memory_kind", None) or "device")
-            if i in self._cold:
-                kinds.add(self._cold[i].sharding.memory_kind)
-        return kinds
+    def spilled_kinds(self) -> Dict[str, str]:
+        """path -> memory kind of every spilled piece (cold tails and
+        fully host-placed leaves) as the pool holds it between steps."""
+        pieces = {i: self._cold[i] for i in self._cold}
+        pieces.update({i: self._hot[i] for i in self._host_leaves})
+        return {self._paths[i]: x.sharding.memory_kind
+                for i, x in sorted(pieces.items())}
 
     def _bytes(self, leaves) -> int:
         return sum(int(x.size) * x.dtype.itemsize for x in leaves)

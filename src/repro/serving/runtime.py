@@ -20,9 +20,12 @@ these pieces):
    the modeled throttle factor and energy for the co-run, so the paper's
    Figs. 5–7 quantities can be read off a live serving run.
 
-On this CPU container the slices are logical (every tenant executes on
-the host backend); the partitioner, plans, memory kinds, and power
-accounting are exactly what a pod-scale deployment would use.
+The pod grid is modeled: every tenant executes on the runtime's own
+``mesh`` (one chip, the four chips of one host, or CPU devices in tests),
+whatever rectangle the partitioner gave it. The offload plan, the memory
+kinds it places, and the power accounting are what a pod-scale deployment
+would use; parameters the plan spills stay in ``pinned_host`` and are
+copied to device memory explicitly for each step (``fetch_to_device``).
 """
 from __future__ import annotations
 
@@ -91,7 +94,7 @@ class SliceRuntime:
                  partitioner: Optional[StaticPartitioner] = None,
                  perf: Optional[PerfModel] = None):
         self.pod = pod
-        self.mesh = mesh   # execution mesh (host backend here); placement
+        self.mesh = mesh   # execution mesh shared by every tenant
         # an externally owned partitioner lets a cluster-level scheduler
         # (repro.cluster) share one pod grid between its own modeled jobs
         # and this runtime's live tenants

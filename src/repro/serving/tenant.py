@@ -13,6 +13,8 @@ surfaces are the ones the paper identifies (host link, pod power), which
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, List, Optional
@@ -20,11 +22,28 @@ from typing import Any, Deque, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.offload import OffloadPlan
+from repro.core.offload import OffloadPlan, fetch_to_device
 from repro.serving.kv_pool import KVPool
 
 PyTree = Any
+
+
+# One compiled program per (model, shapes, shardings), shared by every
+# engine that serves an equal model.
+@functools.partial(jax.jit, static_argnums=0)
+def _prefill_step(model, params, tokens):
+    _, _, cache = model.forward(params, {"tokens": tokens},
+                                return_cache=True, last_token_only=True)
+    return cache
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _decode_step(model, params, cache, tokens, pos):
+    logits, new_cache = model.decode(params, cache,
+                                     {"tokens": tokens, "pos": pos})
+    return logits, jnp.argmax(logits, axis=-1), new_cache
 
 
 @dataclass
@@ -93,6 +112,17 @@ class TenantEngine:
         self.outputs: Dict[int, List[int]] = {}      # rid -> generated
         self.stats = TenantStats()
         self.ticks = 0
+        self.last_logits = None   # (slots, vocab) logits of the latest tick
+
+    def _inputs(self, x: np.ndarray):
+        """Host array -> step input, replicated over the engine's mesh."""
+        if self.mesh is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, NamedSharding(self.mesh, P()))
+
+    def _mesh_scope(self):
+        return (contextlib.nullcontext() if self.mesh is None
+                else jax.set_mesh(self.mesh))
 
     # -- compatibility properties (pre-refactor ServingEngine surface) -----
     @property
@@ -134,8 +164,10 @@ class TenantEngine:
         if slot is None:
             return False
         req.slot = slot
-        batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None, :]}
-        _, _, pc = self.model.forward(self.params, batch, return_cache=True)
+        tokens = self._inputs(np.asarray(req.prompt, np.int32)[None, :])
+        with self._mesh_scope():
+            pc = _prefill_step(self.model, fetch_to_device(self.params),
+                               tokens)
         plen = len(req.prompt)
         self.pool.paste(slot, pc, plen)
         self.live[slot] = req
@@ -178,13 +210,15 @@ class TenantEngine:
             last = (req.generated[-1] if req.generated else int(req.prompt[-1]))
             tokens[slot, 0] = last
         # per-row cache positions: ragged continuous batching
-        batch = {"tokens": jnp.asarray(tokens),
-                 "pos": jnp.asarray(self.pool.positions, jnp.int32)}
-        logits, new_cache = self.model.decode(
-            self.params, self.pool.materialize(), batch)
+        with self._mesh_scope():
+            logits, next_tokens, new_cache = _decode_step(
+                self.model, fetch_to_device(self.params),
+                self.pool.materialize(), self._inputs(tokens),
+                self._inputs(self.pool.positions.astype(np.int32)))
         self.pool.update(new_cache)
+        self.last_logits = logits
         emitted = 0
-        next_tokens = np.asarray(jnp.argmax(logits, axis=-1))
+        next_tokens = np.asarray(next_tokens)
         for slot, req in list(self.live.items()):
             req.generated.append(int(next_tokens[slot]))
             self.pool.positions[slot] += 1

@@ -161,6 +161,21 @@ def test_repack_never_shrinks_largest_placeable(names, data):
             >= (before.n_chips if before else 0))
 
 
+def test_repack_keeps_layout_when_first_fit_would_strand():
+    """Largest-first first-fit from a clean grid can strand more than the
+    layout it replaces (a 64-chip hole became 32 here): repack then keeps
+    the old layout and moves nothing."""
+    part = StaticPartitioner()
+    for name in ["1s.16c", "1s.16c", "4s.64c", "1s.16c", "4s.64c"]:
+        part.allocate(get_profile(name))
+    origins = {sid: a.origin for sid, a in part.allocations.items()}
+    assert part.largest_free_profile().n_chips == 64
+    assert part.repack() == {}
+    assert part.largest_free_profile().n_chips == 64
+    assert {sid: a.origin for sid, a in part.allocations.items()} == origins
+    part.validate()
+
+
 # (the deterministic rollback test lives in test_slice_runtime.py so it
 # also runs where hypothesis is unavailable)
 

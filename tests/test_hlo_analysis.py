@@ -98,8 +98,8 @@ def test_collectives_counted_with_trips():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.hlo_analysis import analyze_hlo
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((8,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("model",))
         def f(x, w):
             def body(c, _):
                 # contraction over the model-sharded dim -> all-reduce that

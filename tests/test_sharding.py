@@ -29,11 +29,11 @@ def test_sharded_train_step_runs_and_matches_single_device():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.configs.shapes import ShapeSuite, TRAIN
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_mesh
         from repro.models.model_zoo import build_model
         from repro.models.common import host_axis_env
 
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = get_config("llama3-8b").reduced().with_(
             num_heads=4, num_kv_heads=2, remat="none")
         shape = ShapeSuite("t", TRAIN, 64, 4)
@@ -84,9 +84,9 @@ def test_compressed_grad_sync_reduces_dcn_bytes():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.hlo_analysis import analyze_hlo
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_mesh
         from repro.optim.compression import cross_pod_sync, init_error_feedback
-        mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         grads = {"w": jnp.ones((256, 256), jnp.float32)}
         err = init_error_feedback(grads)
 

@@ -11,8 +11,8 @@ import pytest
 from repro.configs import get_config
 from repro.core.hw import V5E_POD
 from repro.core.offload import (OffloadPlan, device_memory_kind,
-                                host_memory_kind, plan_offload,
-                                shardings_with_offload)
+                                host_memory_kind, in_host_memory,
+                                plan_offload, shardings_with_offload)
 from repro.core.partitioner import StaticPartitioner
 from repro.core.slices import get_profile
 from repro.launch.mesh import make_host_mesh
@@ -272,6 +272,48 @@ def test_engine_equivalence_offload_on_off(gpt2, mesh):
     assert eng.pool.split_leaves, "partial plan must split a kv leaf"
     assert eng.pool.host_bytes > 0 and eng.pool.device_bytes > 0
     assert base == eng.run(reqs())
+
+
+def test_split_pool_and_host_params_logits_match_device(gpt2, mesh):
+    """A plan that spills the embedding table and a cold KV tail to
+    pinned_host runs the same program on the same values as the all-device
+    tenant: every tick's logits are bit-identical, and between ticks the
+    spilled bytes stay in host memory."""
+    cfg, model, _ = gpt2
+    prompts = [np.arange(3, 11, dtype=np.int32) % cfg.vocab_size,
+               np.arange(1, 6, dtype=np.int32) % cfg.vocab_size]
+
+    def serve(budget):
+        rt = SliceRuntime(mesh=mesh)
+        t = rt.add_tenant(TenantSpec("t", cfg, profile="1s.16c", slots=2,
+                                     max_seq=48, hbm_budget=budget,
+                                     spill_granule=1024))
+        rt.submit("t", [Request(i, p, 6) for i, p in enumerate(prompts)])
+        logits = []
+        while not t.engine.idle:
+            rt.step()
+            logits.append(np.asarray(t.engine.last_logits))
+            if budget is not None:
+                assert in_host_memory(t.params["tok_embed"])
+                kinds = t.engine.pool.spilled_kinds()
+                assert set(kinds) == set(t.engine.pool.split_leaves)
+                assert set(kinds.values()) == {"pinned_host"}
+        return t, logits
+
+    base, want = serve(None)
+    inv = model.serving_inventory(
+        base.params, model.init_cache(2, 48))
+    embed = sum(x.bytes for x in inv if x.group == "embed")
+    kv = sum(x.bytes for x in inv if x.group == "kv_cache")
+    off, got = serve(base.inventory_bytes - embed - kv // 4)
+    assert set(off.plan.offloaded) == {"params/tok_embed",
+                                       "params/pos_embed"}
+    assert off.engine.pool.split_leaves
+    assert off.engine.pool.host_bytes > 0
+    assert base.engine.pool.host_bytes == 0
+    assert len(got) == len(want)
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_eviction_records_partial_generation(gpt2):
