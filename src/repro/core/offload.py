@@ -23,6 +23,7 @@ from dataclasses import dataclass, field, replace
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 
 from repro.core.hw import ChipSpec, HostSpec, V5E, V5E_HOST
@@ -394,9 +395,14 @@ def fetch_to_device(tree: PyTree) -> PyTree:
     JAX computes only on operands in one memory space, so a spilled leaf
     enters a step through this copy; the host-resident original stays where
     the plan put it and the device copy is dropped after the step."""
-    return jax.tree_util.tree_map(
-        lambda x: (jax.device_put(x, x.sharding.with_memory_kind("device"))
-                   if in_host_memory(x) else x), tree)
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    host = [i for i, x in enumerate(leaves) if in_host_memory(x)]
+    with TraceAnnotation("offload.fetch",
+                         h2d_bytes=sum(leaves[i].nbytes for i in host)):
+        for i in host:
+            leaves[i] = jax.device_put(
+                leaves[i], leaves[i].sharding.with_memory_kind("device"))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def shardings_with_offload(spec_tree: PyTree, plan: OffloadPlan, mesh, *,

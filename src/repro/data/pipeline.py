@@ -20,6 +20,7 @@ from typing import Dict, Iterator, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class SyntheticSource:
@@ -65,20 +66,23 @@ class DataPipeline:
         def producer():
             step = self.start_step
             while not stop.is_set():
-                arr = self.source.batch(step, self.global_batch, self.seq_len)
-                q.put((step, arr))
+                q.put(self.source.batch(step, self.global_batch, self.seq_len))
                 step += 1
 
         t = threading.Thread(target=producer, daemon=True)
         t.start()
         try:
+            step = self.start_step
             while True:
-                _, arr = q.get()
-                tokens, labels = arr[:, :-1], arr[:, 1:]
-                if self.sharding is not None:
-                    tokens = jax.device_put(tokens, self.sharding)
-                    labels = jax.device_put(labels, self.sharding)
+                # the step loop's wait for its batch, on the profiler's clock
+                with TraceAnnotation("data.next", step=step):
+                    arr = q.get()
+                    tokens, labels = arr[:, :-1], arr[:, 1:]
+                    if self.sharding is not None:
+                        tokens = jax.device_put(tokens, self.sharding)
+                        labels = jax.device_put(labels, self.sharding)
                 yield {"tokens": tokens, "labels": labels}
+                step += 1
         finally:
             stop.set()
 

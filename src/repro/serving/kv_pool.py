@@ -28,6 +28,7 @@ from typing import Any, Dict, List, Optional, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 
 from repro.core.offload import (OffloadPlan, _flatten_with_paths,
@@ -119,6 +120,11 @@ class KVPool:
                 if kind != "host":
                     leaf = jax.device_put(leaf, self._dev_sharding[i])
             self._hot.append(leaf)
+        # planned host-tier bytes (cold tails + fully spilled leaves): what
+        # materialize copies in and update writes back, every call
+        self.host_bytes = (
+            self._bytes(self._cold.values())
+            + self._bytes(self._hot[i] for i in self._host_leaves))
 
     # ------------------------------------------------------------------
     def _decide(self, full_path: str, leaf, plan: Optional[OffloadPlan],
@@ -161,15 +167,16 @@ class KVPool:
         """Full cache tree in device memory for one step: spilled leaves and
         cold tails are copied in explicitly, tails concatenated back on."""
         leaves = []
-        for i, leaf in enumerate(self._hot):
-            if i in self._host_leaves:
-                leaf = jax.device_put(leaf, self._dev_sharding[i])
-            elif i in self._cold:
-                cold = jax.device_put(self._cold[i], self._dev_sharding[i])
-                leaf = jax.device_put(
-                    jnp.concatenate([leaf, cold], axis=SEQ_AXIS),
-                    self._dev_sharding[i])
-            leaves.append(leaf)
+        with TraceAnnotation("kv.materialize", h2d_bytes=self.host_bytes):
+            for i, leaf in enumerate(self._hot):
+                if i in self._host_leaves:
+                    leaf = jax.device_put(leaf, self._dev_sharding[i])
+                elif i in self._cold:
+                    cold = jax.device_put(self._cold[i], self._dev_sharding[i])
+                    leaf = jax.device_put(
+                        jnp.concatenate([leaf, cold], axis=SEQ_AXIS),
+                        self._dev_sharding[i])
+                leaves.append(leaf)
         return jax.tree_util.tree_unflatten(self._treedef, leaves)
 
     def update(self, new_cache: PyTree) -> None:
@@ -178,27 +185,28 @@ class KVPool:
         (the write-back half of the DMA round trip)."""
         leaves = jax.tree_util.tree_leaves(new_cache)
         assert len(leaves) == len(self._hot), "cache structure changed"
-        for i, leaf in enumerate(leaves):
-            if i in self._cold:
-                hot_len = self._hot_len[i]
-                self._hot[i] = jax.device_put(_seq_slice(leaf, 0, hot_len),
-                                              self._dev_sharding[i])
-                self._cold[i] = jax.device_put(
-                    _seq_slice(leaf, hot_len, self.max_seq),
-                    self._host_sharding[i])
-            elif i in self._host_leaves:
-                self._hot[i] = jax.device_put(leaf, self._host_sharding[i])
-            elif self.mesh is not None:
-                # keep the pool's layout fixed so the step never recompiles
-                self._hot[i] = jax.device_put(leaf, self._dev_sharding[i])
-            else:
-                self._hot[i] = leaf
-        # Every step rewrites the whole pool (and a paste, one per admitted
-        # request, does too). Left asynchronous, the host queues the next
-        # rewrite while earlier pools and their host copies are still
-        # alive: on a 16 GB chip that stacked ~3.4 GB of pool copies.
-        # Waiting holds it to one old and one new pool.
-        jax.block_until_ready((self._hot, list(self._cold.values())))
+        with TraceAnnotation("kv.update", d2h_bytes=self.host_bytes):
+            for i, leaf in enumerate(leaves):
+                if i in self._cold:
+                    hot_len = self._hot_len[i]
+                    self._hot[i] = jax.device_put(_seq_slice(leaf, 0, hot_len),
+                                                  self._dev_sharding[i])
+                    self._cold[i] = jax.device_put(
+                        _seq_slice(leaf, hot_len, self.max_seq),
+                        self._host_sharding[i])
+                elif i in self._host_leaves:
+                    self._hot[i] = jax.device_put(leaf, self._host_sharding[i])
+                elif self.mesh is not None:
+                    # keep the pool's layout fixed so the step never recompiles
+                    self._hot[i] = jax.device_put(leaf, self._dev_sharding[i])
+                else:
+                    self._hot[i] = leaf
+            # Every step rewrites the whole pool (and a paste, one per admitted
+            # request, does too). Left asynchronous, the host queues the next
+            # rewrite while earlier pools and their host copies are still
+            # alive: on a 16 GB chip that stacked ~3.4 GB of pool copies.
+            # Waiting holds it to one old and one new pool.
+            jax.block_until_ready((self._hot, list(self._cold.values())))
 
     def paste(self, slot: int, prefix_cache: PyTree, plen: int) -> None:
         """Write a prefill prefix into one slot (the admit path)."""
@@ -233,12 +241,6 @@ class KVPool:
         """Planned HBM-resident bytes (hot prefixes + unspilled leaves)."""
         return self._bytes(leaf for i, leaf in enumerate(self._hot)
                            if i not in self._host_leaves)
-
-    @property
-    def host_bytes(self) -> int:
-        """Planned host-tier bytes (cold tails + fully spilled leaves)."""
-        return (self._bytes(self._cold.values())
-                + self._bytes(self._hot[i] for i in self._host_leaves))
 
     @property
     def split_leaves(self) -> Dict[str, int]:

@@ -302,7 +302,6 @@ class SliceRuntime:
                 "plan_partial": [n for n, _ in tenant.plan.partial],
                 "kv_device_bytes": eng.pool.device_bytes,
                 "kv_host_bytes": eng.pool.host_bytes,
-                "latency": eng.stats.latency_percentiles(),
             }
             if self.perf.twin is not None:
                 # twin-offload pricing for this tenant's rectangle: the

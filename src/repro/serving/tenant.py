@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, List, Optional
@@ -22,6 +23,7 @@ from typing import Any, Deque, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.offload import OffloadPlan, fetch_to_device
@@ -54,18 +56,17 @@ class Request:
     generated: List[int] = field(default_factory=list)
     slot: Optional[int] = None
     truncated: bool = False      # evicted at max_seq before max_new_tokens
-    # latency stamps, in engine ticks (the engine's unit of time):
+    # engine ticks at each stage; they pin the order of admission
     submit_tick: Optional[int] = None   # queued (or first seen at prefill)
     admit_tick: Optional[int] = None    # slot claimed, prefix written
     finish_tick: Optional[int] = None   # completed/evicted, end of that tick
+    # time.perf_counter() when queued: the queue wait that the
+    # ``engine.admit`` span reports is measured from here
+    submit_s: Optional[float] = None
 
     @property
     def done(self) -> bool:
         return len(self.generated) >= self.max_new_tokens
-
-
-def _pct(xs: List[int], q: float) -> float:
-    return float(np.percentile(np.asarray(xs, dtype=float), q)) if xs else 0.0
 
 
 @dataclass
@@ -77,19 +78,6 @@ class TenantStats:
     rejected: int = 0
     completed: int = 0
     truncated: int = 0
-    # per-request latency samples (ticks): admission-queue wait and
-    # end-to-end submit → completion — the autoscaler's SLO signal
-    queue_wait_ticks: List[int] = field(default_factory=list)
-    e2e_ticks: List[int] = field(default_factory=list)
-
-    def latency_percentiles(self) -> Dict[str, float]:
-        """p50/p99 of queue wait and end-to-end latency, in ticks."""
-        return {
-            "queue_wait_p50": _pct(self.queue_wait_ticks, 50),
-            "queue_wait_p99": _pct(self.queue_wait_ticks, 99),
-            "e2e_p50": _pct(self.e2e_ticks, 50),
-            "e2e_p99": _pct(self.e2e_ticks, 99),
-        }
 
 
 class TenantEngine:
@@ -143,6 +131,7 @@ class TenantEngine:
             return False
         if req.submit_tick is None:
             req.submit_tick = self.ticks
+            req.submit_s = time.perf_counter()
         self.queue.append(req)
         return True
 
@@ -160,21 +149,23 @@ class TenantEngine:
                 f"request {req.rid}: prompt length {len(req.prompt)} "
                 f"exceeds max_seq-1 ({self.max_seq - 1}) — queue path "
                 f"rejects these; direct prefill callers must pre-check")
-        slot = self.pool.alloc_slot()
-        if slot is None:
+        if not self.pool.free_slots:
             return False
-        req.slot = slot
-        tokens = self._inputs(np.asarray(req.prompt, np.int32)[None, :])
-        with self._mesh_scope():
-            pc = _prefill_step(self.model, fetch_to_device(self.params),
-                               tokens)
-        plen = len(req.prompt)
-        self.pool.paste(slot, pc, plen)
-        self.live[slot] = req
+        now = time.perf_counter()
         if req.submit_tick is None:
-            req.submit_tick = self.ticks   # direct-admit callers skip submit()
+            # direct-admit callers skip submit()
+            req.submit_tick, req.submit_s = self.ticks, now
+        plen = len(req.prompt)
+        with TraceAnnotation("engine.admit", rid=req.rid, prompt_len=plen,
+                             wait_ms=1e3 * (now - req.submit_s)):
+            slot = req.slot = self.pool.alloc_slot()
+            tokens = self._inputs(np.asarray(req.prompt, np.int32)[None, :])
+            with self._mesh_scope():
+                pc = _prefill_step(self.model, fetch_to_device(self.params),
+                                   tokens)
+            self.pool.paste(slot, pc, plen)
+        self.live[slot] = req
         req.admit_tick = self.ticks
-        self.stats.queue_wait_ticks.append(req.admit_tick - req.submit_tick)
         self.stats.admitted += 1
         self.stats.prefill_tokens += plen
         return True
@@ -232,7 +223,6 @@ class TenantEngine:
                     self.stats.truncated += 1
                 self.stats.completed += 1
                 req.finish_tick = self.ticks + 1   # done by this tick's end
-                self.stats.e2e_ticks.append(req.finish_tick - req.submit_tick)
                 self.outputs[req.rid] = req.generated
                 del self.live[slot]
                 self.pool.free_slot(slot)

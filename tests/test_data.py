@@ -53,3 +53,23 @@ def test_byte_corpus_source():
         np.testing.assert_array_equal(b, src.batch(0, 3, 32))
     finally:
         os.unlink(path)
+
+
+def test_each_batch_handed_over_in_a_data_next_span(tmp_path):
+    """The step loop's wait for a batch is the ``data.next`` span, numbered
+    by the step it feeds, on the profiler's clock."""
+    import jax
+    from jax.profiler import ProfileData
+    pipe = DataPipeline(SyntheticSource(500, seed=3), 2, 8, start_step=5,
+                        sharding=jax.sharding.SingleDeviceSharding(
+                            jax.devices()[0]))
+    it = iter(pipe)
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(3):
+            next(it)
+    it.close()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    host = ProfileData.from_file(str(path)).find_plane_with_name("/host:CPU")
+    spans = [dict(e.stats) for line in host.lines for e in line.events
+             if e.name == "data.next"]
+    assert spans == [{"step": 5}, {"step": 6}, {"step": 7}]

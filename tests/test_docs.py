@@ -1,5 +1,5 @@
 """Docs stay true: every relative markdown link under docs/ resolves to a
-real file, and the code blocks in docs/scheduling.md execute as doctests
+real file, and the worked examples' code blocks execute as doctests
 (the worked example cannot rot). CI runs this file as the docs job."""
 import doctest
 import re
@@ -45,7 +45,7 @@ def test_relative_links_resolve(md):
 
 @pytest.mark.parametrize("name", ["scheduling.md", "cluster.md",
                                   "autoscaling.md", "offloading.md",
-                                  "hardware.md"])
+                                  "hardware.md", "serving.md"])
 def test_worked_examples_execute(name, monkeypatch):
     monkeypatch.chdir(REPO)   # examples use repo-relative fixture paths
     text = (DOCS / name).read_text(encoding="utf-8")

@@ -316,6 +316,48 @@ def test_split_pool_and_host_params_logits_match_device(gpt2, mesh):
         np.testing.assert_array_equal(a, b)
 
 
+def test_spans_count_the_tiers_bytes(gpt2, mesh, tmp_path):
+    """Under the profiler every admission is an ``engine.admit`` span with
+    its request, prompt length and queue wait, and every prefill and
+    decode moves the host-placed parameters in (``offload.fetch``) and the
+    pool's host bytes in and out (``kv.materialize``, ``kv.update``)."""
+    from jax.profiler import ProfileData
+    cfg, model, params = gpt2
+    inv = model.serving_inventory(params, model.init_cache(2, 48))
+    total = sum(x.bytes for x in inv)
+    embed = sum(x.bytes for x in inv if x.group == "embed")
+    kv = sum(x.bytes for x in inv if x.group == "kv_cache")
+    rt = SliceRuntime(mesh=mesh)
+    t = rt.add_tenant(TenantSpec(
+        "t", cfg, profile="1s.16c", slots=2, max_seq=48,
+        hbm_budget=total - embed - kv // 4, spill_granule=1024))
+    prompts = [np.arange(3, 11, dtype=np.int32),
+               np.arange(1, 6, dtype=np.int32)]
+    rt.submit("t", [Request(i, p, 4) for i, p in enumerate(prompts)])
+    with jax.profiler.trace(str(tmp_path)):
+        rt.step()      # two admissions, then a decode
+        rt.step()      # a decode alone
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    host = ProfileData.from_file(str(path)).find_plane_with_name("/host:CPU")
+    spans = {}
+    for line in host.lines:
+        for e in line.events:
+            if e.name.startswith(("engine.", "kv.", "offload.")):
+                spans.setdefault(e.name, []).append(dict(e.stats))
+
+    admits = spans["engine.admit"]
+    assert [(a["rid"], a["prompt_len"]) for a in admits] == [(0, 8), (1, 5)]
+    assert all(a["wait_ms"] >= 0 for a in admits)
+    pool = t.engine.pool
+    params_host = sum(x.nbytes for x in jax.tree_util.tree_leaves(t.params)
+                      if in_host_memory(x))
+    assert params_host == embed and pool.host_bytes > 0
+    moves = len(admits) + 2      # each prefill and each decode
+    assert spans["offload.fetch"] == [{"h2d_bytes": embed}] * moves
+    assert spans["kv.materialize"] == [{"h2d_bytes": pool.host_bytes}] * moves
+    assert spans["kv.update"] == [{"d2h_bytes": pool.host_bytes}] * moves
+
+
 def test_eviction_records_partial_generation(gpt2):
     cfg, model, params = gpt2
     prompt = np.arange(1, 9, dtype=np.int32) % cfg.vocab_size
@@ -378,11 +420,6 @@ def test_runtime_serves_tenants_concurrently(gpt2, mesh):
     for name in ("a", "b"):
         row = report["tenants"][name]
         assert row["tokens_out"] == 12 and row["completed"] == 3
-        # per-tenant latency percentiles surface through the report
-        lat = row["latency"]
-        assert set(lat) == {"queue_wait_p50", "queue_wait_p99",
-                            "e2e_p50", "e2e_p99"}
-        assert lat["e2e_p99"] >= lat["e2e_p50"] > 0.0
     assert report["pod_utilization"] == pytest.approx(48 / 256)
     assert 0 < report["modeled"]["throttle"] <= 1.0
     # release + repack path
