@@ -1,4 +1,13 @@
-"""Jit'd public wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels.
+
+* ``causal_flash_attention`` — differentiable causal self-attention in the
+  model's (B, S, H, hd) layout (custom VJP over the forward-with-stats and
+  the two backward kernels); ``models.attention.attention_core`` sends
+  causal self-attention on a TPU here.
+* ``flash_attention`` — forward only, (B, S, H, hd) (``attn_impl="pallas"``).
+* ``flash_attention_grads`` — out and (dq, dk, dv) in the folded (BH, S, hd)
+  layout, for tests.
+* ``ssd``, ``grouped_matmul``, ``stream_matmul``.
 
 On the CPU backend the wrappers run the kernels in interpret mode (Python
 emulation of the kernel body — bit-accurate block semantics, no Mosaic), so
@@ -22,21 +31,79 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
+def _fold(t):
+    """(B, S, H, hd) -> (B*H, S, hd)."""
+    B, S, H, hd = t.shape
+    return t.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
+
+
+def _unfold(t, B: int, H: int):
+    """(B*H, S, hd) -> (B, S, H, hd)."""
+    return t.reshape(B, H, *t.shape[1:]).transpose(0, 2, 1, 3)
+
+
+def _in_place(shape) -> bool:
+    """Whether the kernels read (B, S, H, hd) in place, as (B, S, H * hd):
+    where their blocks of whole heads are 128-lane aligned (gpt2's pairs
+    of 64, heads of 128); else heads are folded into the batch."""
+    _, _, H, hd = shape
+    return _fa.lanes(H * hd, hd) % _fa.LANES == 0
+
+
+def _to_kernel(t):
+    B, S, H, hd = t.shape
+    return t.reshape(B, S, H * hd) if _in_place(t.shape) else _fold(t)
+
+
+def _from_kernel(t, shape):
+    B, _, H, _ = shape
+    return t.reshape(shape) if _in_place(shape) else _unfold(t, B, H)
+
+
+@jax.custom_vjp
+def causal_flash_attention(q, k, v):
+    """Causal self-attention, q, k, v: (B, S, H, hd), scale hd ** -0.5.
+    The forward saves (q, k, v, out, lse) as the kernels see them; nothing
+    S x S reaches HBM in the forward or the backward."""
+    out = _fa.flash_attention_fwd(
+        _to_kernel(q), _to_kernel(k), _to_kernel(v), causal=True,
+        head_dim=q.shape[-1], interpret=_interpret())
+    return _from_kernel(out, q.shape)
+
+
+def _causal_fwd(q, k, v):
+    qk, kk, vk = _to_kernel(q), _to_kernel(k), _to_kernel(v)
+    out, lse = _fa.flash_attention_fwd_stats(
+        qk, kk, vk, causal=True, head_dim=q.shape[-1],
+        interpret=_interpret())
+    return _from_kernel(out, q.shape), (qk, kk, vk, out, lse)
+
+
+def _causal_bwd(res, dout):
+    qk, kk, vk, out, lse = res
+    grads = _fa.flash_attention_bwd(
+        qk, kk, vk, out, lse, _to_kernel(dout), causal=True,
+        head_dim=dout.shape[-1], interpret=_interpret())
+    return tuple(_from_kernel(g, dout.shape) for g in grads)
+
+
+causal_flash_attention.defvjp(_causal_fwd, _causal_bwd)
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
-def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128):
+def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
+                    block_k=None):
     """q, k, v: (B, S, H, hd) — heads are folded/unfolded here."""
     B, S, H, hd = q.shape
-    fold = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, t.shape[1], hd)
     out = _fa.flash_attention_fwd(
-        fold(q), fold(k), fold(v), causal=causal,
+        _fold(q), _fold(k), _fold(v), causal=causal,
         block_q=block_q, block_k=block_k, interpret=_interpret())
-    return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
+    return _unfold(out, B, H)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
 def flash_attention_grads(q, k, v, dout, *, causal: bool = True,
-                          block_q: int = 128, block_k: int = 128):
+                          block_q=None, block_k=None):
     """Full flash backward via the Pallas kernels.
     q, k, v, dout: (BH, S, hd). Returns (out, dq, dk, dv)."""
     interp = _interpret()

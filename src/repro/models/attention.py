@@ -1,12 +1,22 @@
-"""Attention: XLA flash (scan + online softmax), GQA, RoPE/M-RoPE, decode.
+"""Attention: fused Pallas flash on the TPU, XLA flash (scan + online
+softmax), GQA, RoPE/M-RoPE, decode.
 
-Two implementations share one signature:
-  * ``attn_impl="xla"`` — a lax.scan over KV chunks with online softmax; this
-    is the path used by the dry-run and all training lowering. Peak memory is
-    O(Sq * chunk) instead of O(Sq * Sk), which is what makes the 32k-prefill
-    cells compile with sane footprints.
-  * ``attn_impl="pallas"`` — the TPU kernel in ``repro.kernels.flash_attention``
-    (validated against ``repro.kernels.ref`` in interpret mode).
+``attention_core`` picks the path from what it is given, not from a flag:
+
+  * causal self-attention (Sq == Sk, no ragged ``kv_len``, no offset, S a
+    multiple of 128, at least ``FUSED_MIN_SCORE_BYTES`` of fp32 scores) on
+    one TPU device — training and long prefill — runs the differentiable
+    Pallas kernels of ``repro.kernels.ops.causal_flash_attention``: score
+    tiles stay in VMEM in the forward, the recomputed forward and the
+    backward;
+  * decode (Sq == 1, not causal) — ``decode_attention``, one pass over the
+    cache;
+  * everything else, and every call off the TPU (the CPU tests, the
+    dry-run) — ``attn_impl="xla"``: a lax.scan over KV chunks with online
+    softmax. Peak memory is O(Sq * chunk) instead of O(Sq * Sk), which is
+    what makes the 32k-prefill cells compile with sane footprints.
+    ``attn_impl="pallas"`` (forward-only kernel) and ``"xla_cv"`` (XLA-level
+    custom VJP) remain as options there.
 
 GQA is handled by gather-expanding K/V head-wise (a local gather — verified to
 introduce zero collectives when Q-heads are model-sharded and KV replicated).
@@ -315,13 +325,42 @@ def _flash_cv_bwd(causal, chunk, scale, res, dout):
 flash_attention_cv.defvjp(_flash_cv_fwd, _flash_cv_bwd)
 
 
+def _on_one_tpu() -> bool:
+    """The computation runs on one TPU device: the TPU backend, and no
+    context mesh of several devices (a Pallas kernel is not partitioned)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return (jax.default_backend() == "tpu"
+            and (mesh.empty or mesh.size == 1))
+
+
+# Below 128 MiB of fp32 scores (B * H * S * S * 4 bytes) the XLA scan
+# beats the fused kernels' fixed cost; past it the scan's time jumps. On a
+# v5e, a phi3-mini prompt took the scan 100 us at 896 tokens (103 MB) and
+# 601 us at 1024 (134 MB); fused took 1.11x and 0.20x of that. The
+# gpt2-124m training step (4 GB) fell from 1361 to 740 ms (PERF.md).
+FUSED_MIN_SCORE_BYTES = 1 << 27
+
+
+def _fused(q, k, *, causal: bool, q_offset, kv_len) -> bool:
+    """Causal self-attention that the fused Pallas kernels take."""
+    B, S, H = q.shape[:3]
+    return (causal and S == k.shape[1] and kv_len is None
+            and isinstance(q_offset, int) and q_offset == 0
+            and S % 128 == 0 and B * H * S * S * 4 >= FUSED_MIN_SCORE_BYTES
+            and _on_one_tpu())
+
+
 def attention_core(cfg: ModelConfig, q, k, v, *, causal: bool, q_offset=0,
                    kv_len=None):
-    """Dispatch on ``cfg.attn_impl``; expands GQA heads first."""
+    """Dispatch on the call's shape and backend, then on ``cfg.attn_impl``;
+    expands GQA heads first."""
     k = expand_kv(k, cfg.num_heads)
     v = expand_kv(v, cfg.num_heads)
     if q.shape[1] == 1 and not causal:
         return decode_attention(q, k, v, kv_len=kv_len)
+    if _fused(q, k, causal=causal, q_offset=q_offset, kv_len=kv_len):
+        from repro.kernels import ops as kops
+        return kops.causal_flash_attention(q, k, v)
     if cfg.attn_impl == "pallas" and causal and q.shape[1] == k.shape[1]:
         from repro.kernels import ops as kops
         return kops.flash_attention(q, k, v, causal=True)

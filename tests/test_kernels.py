@@ -8,6 +8,7 @@ import pytest
 hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 
 
@@ -53,29 +54,232 @@ def test_flash_attention_non_causal():
     assert _rel_err(got, want) < 2e-5
 
 
-@pytest.mark.parametrize("causal,bq,bk", [(True, 64, 64), (True, 128, 64),
-                                          (False, 64, 128)])
-def test_flash_backward_kernel_matches_autodiff(causal, bq, bk):
-    """The Pallas dq/dk/dv kernels against jax.vjp of naive attention."""
+def _naive_attention(q, k, v, causal: bool):
+    """Softmax attention in fp32, (B, S, H, hd)."""
+    S, hd = q.shape[1], q.shape[-1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) / np.sqrt(hd)
+    if causal:
+        mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+        s = jnp.where(mask[None, None], s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1),
+                      v.astype(jnp.float32))
+
+
+# the block the custom-VJP wrapper picks for long sequences
+_BLOCK = fa.pick_block(1 << 14, 128, 2)
+
+
+@pytest.mark.parametrize("causal,bq,bk,n_blocks,dtype,H", [
+    pytest.param(True, 64, 64, None, jnp.float32, 1, id="True-64-64"),
+    pytest.param(True, 128, 64, None, jnp.float32, 1, id="True-128-64"),
+    pytest.param(False, 64, 128, None, jnp.float32, 1, id="False-64-128"),
+    *[pytest.param(True, None, None, n, dt, 2,
+                   id=f"wrapper-{n}-blocks-{jnp.dtype(dt).name}")
+      for dt in (jnp.float32, jnp.bfloat16) for n in (1, 2, 4)],
+    pytest.param(True, None, None, 2, jnp.float32, 3,
+                 id="wrapper-2-blocks-float32-folded"),
+])
+def test_flash_backward_kernel_matches_autodiff(causal, bq, bk, n_blocks,
+                                                dtype, H):
+    """The Pallas dq/dk/dv kernels against jax.vjp of naive attention: the
+    kernels at explicit blocks in the folded (BH, S, hd) layout, and
+    jax.grad through the custom-VJP wrapper ``causal_flash_attention`` in
+    the model's (B, S, H, hd) layout at the blocks it picks, S at 1, 2 and
+    4 of them: two heads of 64 read in place as one 128-lane block, and
+    three, whose 192 lanes the wrapper folds into the batch."""
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
-    BH, S, hd = 4, 256, 64
-    q, k, v, do = (jax.random.normal(kk, (BH, S, hd), jnp.float32)
+    if n_blocks is None:      # the kernels: 4 folded heads of 256
+        B, S, hd = 4, 256, 64
+    else:
+        B, S, hd = 1, n_blocks * _BLOCK, 64
+        assert ops._in_place((B, S, H, hd)) == (H == 2)
+        assert fa.pick_block(S, 128, jnp.dtype(dtype).itemsize) == _BLOCK
+    q, k, v = (jax.random.normal(kk, (B, S, H, hd), dtype) for kk in ks[:3])
+    do = jax.random.normal(ks[3], (B, S, H, hd), jnp.float32)
+    if n_blocks is None:
+        fold = lambda t: t[:, :, 0]  # noqa: E731  (H = 1)
+        out, dq, dk, dv = ops.flash_attention_grads(
+            fold(q), fold(k), fold(v), fold(do), causal=causal,
+            block_q=bq, block_k=bk)
+        got = [t[:, :, None] for t in (out, dq, dk, dv)]
+    else:
+        loss = lambda q, k, v: jnp.sum(  # noqa: E731
+            ops.causal_flash_attention(q, k, v).astype(jnp.float32) * do)
+        got = [ops.causal_flash_attention(q, k, v),
+               *jax.grad(loss, argnums=(0, 1, 2))(q, k, v)]
+    want_out, vjp = jax.vjp(lambda *a: _naive_attention(*a, causal), q, k, v)
+    want = [want_out, *vjp(do)]
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == dtype, name
+        assert _rel_err(a, b) < tol, name
+
+
+@pytest.mark.parametrize("bq,bk", [(64, 64), (128, 64), (64, 128)])
+def test_causal_clamp_leaves_results_unchanged(bq, bk, monkeypatch):
+    """Under causality the index maps send a skipped step to the block
+    already resident, so it issues no copy: every step that computes sees
+    its own block, and out, lse, dq, dk and dv are bit-identical to the
+    kernels' with unclamped maps."""
+    for iq in range(512 // bq):
+        for ik in range(512 // bk):
+            needed = ik * bk <= iq * bq + bq - 1
+            kv = int(fa._kv_block_seen(iq, ik, bq, bk))
+            qs = int(fa._q_block_seen(ik, iq, bq, bk))
+            assert (kv == ik) == needed and (qs == iq) == needed
+            if not needed:  # the last block q block iq attends to, and
+                # the first q block that attends to kv block ik
+                assert kv == (iq * bq + bq - 1) // bk
+                assert qs == (ik * bk) // bq
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    q, k, v, do = (jax.random.normal(kk, (2, 512, 128), jnp.float32)
                    for kk in ks)
 
-    def naive(q, k, v):
-        s = jnp.einsum("bqd,bkd->bqk", q, k) / np.sqrt(hd)
-        if causal:
-            mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-            s = jnp.where(mask[None], s, -jnp.inf)
-        return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, -1), v)
+    def run():   # two heads of 64 in each 128-lane block
+        kw = dict(causal=True, head_dim=64, block_q=bq, block_k=bk,
+                  interpret=True)
+        out, lse = fa.flash_attention_fwd_stats(q, k, v, **kw)
+        return (out, lse, *fa.flash_attention_bwd(q, k, v, out, lse, do,
+                                                  **kw))
 
-    out, dq, dk, dv = ops.flash_attention_grads(q, k, v, do, causal=causal,
-                                                block_q=bq, block_k=bk)
-    want_out, vjp = jax.vjp(naive, q, k, v)
-    dq_r, dk_r, dv_r = vjp(do)
-    for name, a, b in (("out", out, want_out), ("dq", dq, dq_r),
-                       ("dk", dk, dk_r), ("dv", dv, dv_r)):
-        assert _rel_err(a, b) < 1e-4, name
+    clamped = run()
+    monkeypatch.setattr(fa, "_kv_block_seen", lambda iq, ik, bq, bk: ik)
+    monkeypatch.setattr(fa, "_q_block_seen", lambda ik, iq, bq, bk: iq)
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), clamped, run()):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+
+
+def _attention_inputs(B, Sq, Sk, H, hd, seed=11):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (B, Sq, H, hd), jnp.bfloat16),
+            jax.random.normal(ks[1], (B, Sk, H, hd), jnp.bfloat16),
+            jax.random.normal(ks[2], (B, Sk, H, hd), jnp.bfloat16))
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """attention_core as it dispatches on one TPU device (the kernels still
+    run in interpret mode here); records the fused calls."""
+    from repro.models import attention
+    calls = []
+    real = ops.causal_flash_attention
+
+    def spy(*a):
+        calls.append(a[0].shape)
+        return real(*a)
+    monkeypatch.setattr(attention, "_on_one_tpu", lambda: True)
+    monkeypatch.setattr(ops, "causal_flash_attention", spy)
+    return calls
+
+
+def test_attention_core_sends_causal_self_attention_to_the_fused_path(
+        on_tpu, monkeypatch):
+    from repro.configs import get_config
+    from repro.models import attention
+    monkeypatch.setattr(attention, "FUSED_MIN_SCORE_BYTES", 0)
+    cfg = get_config("gpt2-124m").reduced()
+    q, k, v = _attention_inputs(2, 256, 256, cfg.num_heads, cfg.head_dim)
+    got = attention.attention_core(cfg, q, k, v, causal=True)
+    assert on_tpu == [q.shape]
+    assert _rel_err(got, _naive_attention(q, k, v, True)) < 2e-2
+    loss = lambda *a: jnp.sum(  # noqa: E731
+        attention.attention_core(cfg, *a, causal=True).astype(jnp.float32))
+    jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    assert len(on_tpu) == 2
+
+
+@pytest.mark.parametrize("case", ["decode", "cross", "kv_len", "offset",
+                                  "ragged_s", "small_scores"])
+def test_attention_core_other_calls_keep_their_paths(case, on_tpu,
+                                                     monkeypatch):
+    """Decode, cross-attention, a ragged kv_len, a query offset, S off the
+    128 grid and scores under the fused path's floor stay on their paths
+    with the dispatch on a TPU: outputs bit-identical to those paths."""
+    from repro.configs import get_config
+    from repro.models import attention
+    cfg = get_config("gpt2-124m").reduced()
+    H, hd = cfg.num_heads, cfg.head_dim
+    flash = lambda q, k, v, **kw: attention.flash_attention(  # noqa: E731
+        q, k, v, chunk=cfg.attn_chunk, **kw)
+    if case == "decode":
+        q, k, v = _attention_inputs(2, 1, 256, H, hd)
+        kw = dict(causal=False, kv_len=jnp.asarray([17, 200]))
+        want = attention.decode_attention(q, k, v, kv_len=kw["kv_len"])
+    elif case == "cross":
+        q, k, v = _attention_inputs(2, 128, 256, H, hd)
+        kw = dict(causal=False)
+        want = flash(q, k, v, causal=False)
+    elif case == "kv_len":
+        q, k, v = _attention_inputs(2, 256, 256, H, hd)
+        kw = dict(causal=True, kv_len=jnp.asarray(200))
+        want = flash(q, k, v, **kw)
+    elif case == "offset":
+        q, k, v = _attention_inputs(2, 128, 128, H, hd)
+        kw = dict(causal=True, q_offset=jnp.asarray(3))
+        want = flash(q, k, v, **kw)
+    elif case == "ragged_s":
+        q, k, v = _attention_inputs(2, 200, 200, H, hd)
+        kw = dict(causal=True)
+        want = flash(q, k, v, **kw)
+    else:
+        q, k, v = _attention_inputs(2, 256, 256, H, hd)
+        kw = dict(causal=True)
+        want = flash(q, k, v, **kw)
+    if case != "small_scores":   # every other case would pass the floor
+        monkeypatch.setattr(attention, "FUSED_MIN_SCORE_BYTES", 0)
+    got = attention.attention_core(cfg, q, k, v, **kw)
+    assert on_tpu == []
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("arch,B,S,fused", [
+    ("phi3-mini-3.8b", 1, 768, False),    # chat prefill: 75 MB of scores
+    ("phi3-mini-3.8b", 1, 1024, True),    # 134 MB
+    ("gpt2-124m", 1, 1024, False),        # 50 MB
+    ("gpt2-124m", 80, 1024, True),        # the training cell: 4 GB
+])
+def test_fused_path_takes_scores_from_its_floor(arch, B, S, fused,
+                                                monkeypatch):
+    """The fused path takes causal self-attention from
+    FUSED_MIN_SCORE_BYTES of fp32 scores up; below, the XLA scan."""
+    from repro.configs import get_config
+    from repro.models import attention
+    monkeypatch.setattr(attention, "_on_one_tpu", lambda: True)
+    cfg = get_config(arch)
+    q = jax.ShapeDtypeStruct((B, S, cfg.num_heads, cfg.head_dim),
+                             jnp.bfloat16)
+    assert attention._fused(q, q, causal=True, q_offset=0,
+                            kv_len=None) == fused
+
+
+def test_fused_path_needs_one_tpu_device(monkeypatch):
+    """A Pallas kernel is not partitioned: under a context mesh of several
+    devices the dispatch keeps off the fused path, on one device it takes
+    it."""
+    from jax.sharding import AbstractMesh, use_abstract_mesh
+    from repro.models import attention
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert attention._on_one_tpu()
+    with use_abstract_mesh(AbstractMesh((1, 1), ("data", "model"))):
+        assert attention._on_one_tpu()
+    with use_abstract_mesh(AbstractMesh((2, 2), ("data", "model"))):
+        assert not attention._on_one_tpu()
+
+
+def test_attention_core_off_the_tpu_keeps_the_xla_scan(monkeypatch):
+    """Off the TPU (the CPU here) causal self-attention stays on the XLA
+    scan, bit-identical to it."""
+    from repro.configs import get_config
+    from repro.models import attention
+    monkeypatch.setattr(ops, "causal_flash_attention", None)
+    cfg = get_config("gpt2-124m").reduced()
+    q, k, v = _attention_inputs(2, 256, 256, cfg.num_heads, cfg.head_dim)
+    assert not attention._on_one_tpu()
+    got = attention.attention_core(cfg, q, k, v, causal=True)
+    want = attention.flash_attention(q, k, v, causal=True,
+                                     chunk=cfg.attn_chunk)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_flash_custom_vjp_matches_autodiff():

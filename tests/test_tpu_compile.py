@@ -14,15 +14,19 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, PartitionSpec as P, SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import flash_attention as fa
-from repro.kernels import moe_gmm, ssd_scan, stream_matmul
+from repro.kernels import moe_gmm, ops, ssd_scan, stream_matmul
+from repro.models import attention
 from repro.models.common import host_axis_env
 from repro.models.model_zoo import build_model
+from repro.optim import adamw
 from repro.serving.tenant import _decode_step
+from repro.train.train_step import TrainStepConfig, make_train_step
 
 V5E_HBM_BYTES = 16 * 10**9
 
@@ -57,7 +61,8 @@ def _compile_kernel(fn, args):
     return compiled
 
 
-# phi3-mini attention: 32 heads of 96, 2048 positions
+# phi3-mini attention: 32 heads of 96, 2048 positions; the kernels take
+# the blocks they pick from the shape, as the wrapper does
 BH, S, HD = 32, 2048, 96
 
 
@@ -71,11 +76,59 @@ def test_flash_attention_fwd_stats_compiles(one_chip):
                     _shapes(one_chip, *[(BH, S, HD)] * 3))
 
 
+def _fwd_bwd(q, k, v, do, head_dim=None):
+    out, lse = fa.flash_attention_fwd_stats(q, k, v, head_dim=head_dim)
+    return fa.flash_attention_bwd(q, k, v, out, lse, do, head_dim=head_dim)
+
+
 def test_flash_attention_bwd_compiles(one_chip):
-    def fwd_bwd(q, k, v, do):
-        out, lse = fa.flash_attention_fwd_stats(q, k, v)
-        return fa.flash_attention_bwd(q, k, v, out, lse, do)
-    _compile_kernel(fwd_bwd, _shapes(one_chip, *[(BH, S, HD)] * 4))
+    _compile_kernel(_fwd_bwd, _shapes(one_chip, *[(BH, S, HD)] * 4))
+
+
+@pytest.mark.parametrize("block", [256, 512, 1024])
+def test_flash_attention_compiles_at_gpt2_training_shape(one_chip, block,
+                                                        monkeypatch):
+    """Forward with stats and backward at gpt2-124m's training shape, 80
+    rows of 1024 positions, 12 heads of 64 in place (blocks of two heads'
+    128 lanes), at each block the shape may be given."""
+    monkeypatch.setattr(fa, "MAX_BLOCK", block)
+    assert fa.lanes(768, 64) == 128 and fa.pick_block(1024, 128, 2) == block
+    _compile_kernel(lambda *a: _fwd_bwd(*a, head_dim=64),
+                    _shapes(one_chip, *[(80, 1024, 768)] * 4))
+
+
+def test_gpt2_train_step_fused_attention_fits_one_chip(one_chip,
+                                                       monkeypatch):
+    """The gpt2-124m train step at 80 x 1024, float32 parameters and bf16
+    compute, with attention dispatched as on one TPU device: the fused
+    kernels are in the program, no f32 score matrix is, and the program
+    fits a 16 GB v5e chip."""
+    monkeypatch.setattr(attention, "_on_one_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    cfg = get_config("gpt2-124m").with_(param_dtype="float32",
+                                        dtype="bfloat16", remat="layer")
+    model = build_model(cfg, mesh)
+    step, sh = make_train_step(model, mesh, TrainStepConfig(),
+                               {"tokens": P(), "labels": P()})
+    place = lambda tree, shard: jax.tree_util.tree_map(  # noqa: E731
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, shard)
+    params = place(model.init(None, abstract=True)[0], sh["params"])
+    opt = place(jax.eval_shape(adamw.init, params), sh["opt"])
+    tokens = jax.ShapeDtypeStruct((80, 1024), jnp.int32,
+                                  sharding=sh["batch"]["tokens"])
+    compiled = step.lower(params, opt, {"tokens": tokens,
+                                        "labels": tokens}).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "flash_attention_bwd_dkv" in text
+    assert "f32[80,12,1024,1024]" not in text
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < V5E_HBM_BYTES, used
 
 
 def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
