@@ -36,8 +36,7 @@ class ModelConfig:
     # --- MoE ---
     num_experts: int = 0
     experts_per_token: int = 0
-    capacity_factor: float = 1.25
-    moe_group_size: int = 8192  # split long sequences into routing sub-groups
+    aux_loss_coef: float = 0.01  # times the load-balance loss summed over layers
 
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
@@ -65,6 +64,12 @@ class ModelConfig:
     norm_eps: float = 1e-5
     act: str = "silu"
     glu: bool = True  # SwiGLU (gated) vs plain 2-matmul MLP
+
+    # --- muP multipliers (Granite); None leaves that path unscaled ---
+    embedding_multiplier: Optional[float] = None  # token embeddings times this
+    residual_multiplier: Optional[float] = None   # each block's output times this
+    logits_scaling: Optional[float] = None        # logits divided by this
+    attention_multiplier: Optional[float] = None  # score scale, else head_dim ** -0.5
 
     # --- numerics / runtime knobs (not architecture) ---
     dtype: str = "bfloat16"

@@ -7,7 +7,7 @@
 * ``flash_attention`` — forward only, (B, S, H, hd) (``attn_impl="pallas"``).
 * ``flash_attention_grads`` — out and (dq, dk, dv) in the folded (BH, S, hd)
   layout, for tests.
-* ``ssd``, ``grouped_matmul``, ``stream_matmul``.
+* ``ssd``, ``stream_matmul``.
 
 On the CPU backend the wrappers run the kernels in interpret mode (Python
 emulation of the kernel body — bit-accurate block semantics, no Mosaic), so
@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
-from repro.kernels import moe_gmm as _gmm
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import stream_matmul as _sm
 
@@ -60,29 +59,30 @@ def _from_kernel(t, shape):
     return t.reshape(shape) if _in_place(shape) else _unfold(t, B, H)
 
 
-@jax.custom_vjp
-def causal_flash_attention(q, k, v):
-    """Causal self-attention, q, k, v: (B, S, H, hd), scale hd ** -0.5.
-    The forward saves (q, k, v, out, lse) as the kernels see them; nothing
-    S x S reaches HBM in the forward or the backward."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def causal_flash_attention(q, k, v, scale=None):
+    """Causal self-attention, q, k, v: (B, S, H, hd); scores times
+    ``scale`` (None: hd ** -0.5). The forward saves (q, k, v, out, lse) as
+    the kernels see them; nothing S x S reaches HBM in the forward or the
+    backward."""
     out = _fa.flash_attention_fwd(
         _to_kernel(q), _to_kernel(k), _to_kernel(v), causal=True,
-        head_dim=q.shape[-1], interpret=_interpret())
+        scale=scale, head_dim=q.shape[-1], interpret=_interpret())
     return _from_kernel(out, q.shape)
 
 
-def _causal_fwd(q, k, v):
+def _causal_fwd(q, k, v, scale):
     qk, kk, vk = _to_kernel(q), _to_kernel(k), _to_kernel(v)
     out, lse = _fa.flash_attention_fwd_stats(
-        qk, kk, vk, causal=True, head_dim=q.shape[-1],
+        qk, kk, vk, causal=True, scale=scale, head_dim=q.shape[-1],
         interpret=_interpret())
     return _from_kernel(out, q.shape), (qk, kk, vk, out, lse)
 
 
-def _causal_bwd(res, dout):
+def _causal_bwd(scale, res, dout):
     qk, kk, vk, out, lse = res
     grads = _fa.flash_attention_bwd(
-        qk, kk, vk, out, lse, _to_kernel(dout), causal=True,
+        qk, kk, vk, out, lse, _to_kernel(dout), causal=True, scale=scale,
         head_dim=dout.shape[-1], interpret=_interpret())
     return tuple(_from_kernel(g, dout.shape) for g in grads)
 
@@ -90,13 +90,14 @@ def _causal_bwd(res, dout):
 causal_flash_attention.defvjp(_causal_fwd, _causal_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
-def flash_attention(q, k, v, *, causal: bool = True, block_q=None,
-                    block_k=None):
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
+                                             "block_k"))
+def flash_attention(q, k, v, *, causal: bool = True, scale=None,
+                    block_q=None, block_k=None):
     """q, k, v: (B, S, H, hd) — heads are folded/unfolded here."""
     B, S, H, hd = q.shape
     out = _fa.flash_attention_fwd(
-        _fold(q), _fold(k), _fold(v), causal=causal,
+        _fold(q), _fold(k), _fold(v), causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=_interpret())
     return _unfold(out, B, H)
 
@@ -120,13 +121,6 @@ def flash_attention_grads(q, k, v, dout, *, causal: bool = True,
 def ssd(x, dt, A, B_, C_, *, chunk: int = 128, nh_block: int = 8):
     return _ssd.ssd_scan(x, dt, A, B_, C_, chunk=chunk, nh_block=nh_block,
                          interpret=_interpret())
-
-
-@functools.partial(jax.jit, static_argnames=("block_c", "block_f", "block_k"))
-def grouped_matmul(x, w, *, block_c: int = 128, block_f: int = 128,
-                   block_k: int = 128):
-    return _gmm.grouped_matmul(x, w, block_c=block_c, block_f=block_f,
-                               block_k=block_k, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k"))

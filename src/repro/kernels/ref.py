@@ -1,10 +1,12 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness ground truth)."""
+"""Pure-jnp oracles for the Pallas kernels and the grouped products (the
+correctness ground truth)."""
 from __future__ import annotations
 
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def attention_ref(q, k, v, *, causal: bool = True, scale=None):
@@ -47,10 +49,18 @@ def ssd_ref(x, dt, A, B_, C_):
     return jnp.moveaxis(ys, 0, 1).astype(x.dtype)  # (B,S,nh,hp)
 
 
-def gmm_ref(x, w):
-    """x: (E, C, d); w: (E, d, f)."""
-    return jnp.einsum("ecd,edf->ecf", x.astype(jnp.float32),
-                      w.astype(jnp.float32)).astype(x.dtype)
+def gmm_ref(x, w, group_sizes):
+    """Grouped products by a loop over the groups: x (M, d) holds the rows
+    of group e (``group_sizes[e]`` of them) after those of groups < e, and
+    each row is multiplied by its group's w[e] (E, d, f) in fp32. Rows past
+    the last group are zero."""
+    out = jnp.zeros((x.shape[0], w.shape[-1]), jnp.float32)
+    start = 0
+    for e, n in enumerate(np.asarray(group_sizes).tolist()):
+        out = out.at[start:start + n].set(
+            x[start:start + n].astype(jnp.float32) @ w[e].astype(jnp.float32))
+        start += n
+    return out.astype(x.dtype)
 
 
 def matmul_ref(x, w):

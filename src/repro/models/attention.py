@@ -353,23 +353,25 @@ def _fused(q, k, *, causal: bool, q_offset, kv_len) -> bool:
 def attention_core(cfg: ModelConfig, q, k, v, *, causal: bool, q_offset=0,
                    kv_len=None):
     """Dispatch on the call's shape and backend, then on ``cfg.attn_impl``;
-    expands GQA heads first."""
+    expands GQA heads first. Scores are scaled by ``cfg.attention_multiplier``,
+    or by ``head_dim ** -0.5`` where it is None."""
+    scale = cfg.attention_multiplier
     k = expand_kv(k, cfg.num_heads)
     v = expand_kv(v, cfg.num_heads)
     if q.shape[1] == 1 and not causal:
-        return decode_attention(q, k, v, kv_len=kv_len)
+        return decode_attention(q, k, v, kv_len=kv_len, scale=scale)
     if _fused(q, k, causal=causal, q_offset=q_offset, kv_len=kv_len):
         from repro.kernels import ops as kops
-        return kops.causal_flash_attention(q, k, v)
+        return kops.causal_flash_attention(q, k, v, scale)
     if cfg.attn_impl == "pallas" and causal and q.shape[1] == k.shape[1]:
         from repro.kernels import ops as kops
-        return kops.flash_attention(q, k, v, causal=True)
+        return kops.flash_attention(q, k, v, causal=True, scale=scale)
     if (cfg.attn_impl == "xla_cv" and causal and kv_len is None
             and k.shape[1] % min(cfg.attn_chunk, k.shape[1]) == 0):
         return flash_attention_cv(q, k, v, True, cfg.attn_chunk,
-                                  cfg.head_dim ** -0.5)
+                                  scale or cfg.head_dim ** -0.5)
     return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
-                           kv_len=kv_len, chunk=cfg.attn_chunk)
+                           kv_len=kv_len, chunk=cfg.attn_chunk, scale=scale)
 
 
 # ---------------------------------------------------------------------------

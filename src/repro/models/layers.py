@@ -146,7 +146,10 @@ def init_embeddings(b: ParamBuilder):
 
 
 def embed_tokens(cfg: ModelConfig, p, tokens, positions: Optional[jnp.ndarray] = None):
-    x = jnp.take(p["tok_embed"], tokens, axis=0).astype(jnp.dtype(cfg.dtype))
+    x = jnp.take(p["tok_embed"], tokens, axis=0)
+    if cfg.embedding_multiplier is not None:
+        x = x * cfg.embedding_multiplier
+    x = x.astype(jnp.dtype(cfg.dtype))
     if cfg.learned_pos:
         if positions is None:
             positions = jnp.arange(tokens.shape[-1])[None, :]
@@ -161,5 +164,7 @@ def unembed(cfg: ModelConfig, p, x, seq_shard_spec=None):
         # logits instead; the loss is per-token so this is communication-free
         # and caps the (B, S, V) fp32 buffer at 1/model_axis per device.
         x = jax.lax.with_sharding_constraint(x, seq_shard_spec)
+    if cfg.logits_scaling is not None:
+        x = (x.astype(jnp.float32) / cfg.logits_scaling).astype(x.dtype)
     w = p["tok_embed"].T if cfg.tie_embeddings else p["lm_head"]
     return jnp.einsum("...d,dv->...v", x, w.astype(x.dtype))

@@ -58,9 +58,21 @@ class Model:
             return_cache=return_cache, last_token_only=last_token_only)
 
     def loss_fn(self, params, batch) -> jnp.ndarray:
-        logits, aux, _ = self.forward(params, batch)
+        return self.loss_and_stats(params, batch)[0]
+
+    def loss_and_stats(self, params, batch):
+        """(loss, stats): the training loss, the load-balance loss summed
+        over layers times ``aux_loss_coef`` included, and the dict of what
+        a train step reports beside it (empty but for a MoE model's
+        ``moe_load_max``)."""
+        if self.cfg.family == ENCDEC:
+            logits, aux, _ = self.forward(params, batch)
+            stats = {}
+        else:
+            logits, aux, _, stats = tfm.forward_decoder_only(
+                self.cfg, params, batch, self.env, self.pol, stats=True)
         loss = softmax_xent(logits, batch["labels"])
-        return loss + 0.01 * aux
+        return loss + self.cfg.aux_loss_coef * aux, stats
 
     def decode(self, params, cache, batch):
         if self.cfg.family == ENCDEC:

@@ -1,28 +1,29 @@
-"""Mixture-of-Experts with capacity-based dispatch (GShard-style semantics).
+"""Mixture-of-Experts FFN: dropless top-k routing over rows sorted by expert.
 
-Memory-sane formulation: rather than materializing the (tokens, E, C) one-hot
-dispatch tensor of the GShard einsum (20 TB at 1M tokens), routing is computed
-per *group* (= one batch row) with local cumsum + scatter/gather:
+Training and prefill (S > 1), over the T = B * S tokens of a call:
 
-  1. top-k experts per token, position-in-expert via cumsum (local per group),
-  2. slot = expert*C + position; tokens beyond capacity C are DROPPED
-     (classic capacity-factor semantics — the padding/drop waste shows up
-     honestly in the roofline "useful FLOPs" ratio),
-  3. gather tokens into (E, C, d) buffers, run expert FFNs as batched
-     einsum with the expert dim model-sharded (expert parallelism),
-  4. scatter-add back with combine weights.
+  1. the router (float32, d -> E) gives softmax probabilities; each token
+     keeps its top-k experts, their weights renormalised to sum to one;
+  2. the T * k (token, expert) pairs are sorted by expert, and each
+     token's row is gathered once per pair into that order: (T * k, d);
+  3. the expert FFN runs as grouped products over the sorted rows
+     (``jax.lax.ragged_dot``, group e being expert e's ``sizes[e]`` rows);
+     on a TPU, XLA's ragged-dot kernel;
+  4. the rows are gathered back into token order and each token's k
+     outputs are summed with its weights.
 
-Under GSPMD, step-3's einsum against E-sharded expert weights slices the
-(replicated-over-model) dispatch buffers locally per expert shard, and step 4
-reduces across the model axis — the same collective volume as a dense TP MLP.
+No pair is dropped. The device work is set by the shapes, not by where the
+tokens go: the row moves are gathers of T * k rows whose gradients are the
+inverse permutation's gathers (never a scatter-add), the sort is over a
+fixed count of keys, and the grouped products cover all T * k rows however
+they fall into groups.
 
-Decode path (S == 1): per-token capacity dispatch degenerates, and decode is
-weight-bandwidth-bound anyway, so we compute all experts densely and combine
-with router weights — optimal HBM traffic (every expert weight read once),
-inflated-but-tiny FLOPs.
+Decode (S == 1) is weight-bandwidth-bound: every expert runs on the token
+and the routing weights combine them.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -44,109 +45,114 @@ def init_moe(b: ParamBuilder, *, stacked: bool = False):
     b.add("w_out", L + (E, cfg.d_ff, cfg.d_model), lr + ("experts", "none", "d_fsdp"))
 
 
-def capacity(cfg: ModelConfig, group_tokens: int) -> int:
-    c = int(cfg.experts_per_token * group_tokens * cfg.capacity_factor
-            // cfg.num_experts)
-    return max(c, cfg.experts_per_token)
-
-
-def route(cfg: ModelConfig, p, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Router logits -> (top-k weights, top-k expert ids). x: (..., d)."""
+def route(cfg: ModelConfig, p, x) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """x (..., d) -> (router probabilities (..., E), top-k weights (..., k)
+    renormalised, top-k expert ids (..., k)). The weights are read out of
+    the probabilities through a one-hot mask, so their gradient reaches the
+    router without a scatter."""
     logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, cfg.experts_per_token)
+    _, top_e = jax.lax.top_k(probs, cfg.experts_per_token)
+    mask = jax.nn.one_hot(top_e, cfg.num_experts, dtype=jnp.float32)
+    top_w = jnp.sum(mask * probs[..., None, :], axis=-1)
     top_w = top_w / jnp.maximum(jnp.sum(top_w, axis=-1, keepdims=True), 1e-9)
-    return top_w, top_e
+    return probs, top_w, top_e
 
 
-def _dispatch_group(cfg: ModelConfig, x_g, top_w_g, top_e_g, C: int):
-    """Per-group dispatch. x_g: (S, d); top_*: (S, k). Returns
-    (gathered (E*C, d), slot_token (E*C,), keep_w (S, k), slot (S, k))."""
-    S, k = top_e_g.shape
-    E = cfg.num_experts
-    flat_e = top_e_g.reshape(S * k)
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)           # (S*k, E)
-    pos = jnp.cumsum(onehot, axis=0) - 1                           # pos within expert
-    pos = jnp.sum(pos * onehot, axis=-1)                           # (S*k,)
-    keep = pos < C
-    slot = jnp.where(keep, flat_e * C + pos, E * C)                # drop -> OOB
-    token_id = jnp.arange(S * k) // k
-    # slot -> token mapping (scatter; OOB drops)
-    slot_token = jnp.full((E * C + 1,), S, jnp.int32)              # S = pad token
-    slot_token = slot_token.at[slot].set(token_id, mode="drop")[:E * C]
-    x_pad = jnp.concatenate([x_g, jnp.zeros((1, x_g.shape[-1]), x_g.dtype)], axis=0)
-    gathered = jnp.take(x_pad, slot_token, axis=0)                 # (E*C, d)
-    keep_w = jnp.where(keep.reshape(S, k), top_w_g, 0.0)
-    return gathered, slot_token, keep_w, slot.reshape(S, k)
+def load_balance_loss(cfg: ModelConfig, probs, top_e) -> jnp.ndarray:
+    """Auxiliary load-balancing loss (Switch-style): E * sum_e f_e * P_e,
+    f_e the share of the routed pairs that went to expert e, P_e its mean
+    router probability, both over every token of the call."""
+    frac = jnp.mean(jax.nn.one_hot(top_e, cfg.num_experts, dtype=jnp.float32),
+                    axis=tuple(range(top_e.ndim)))
+    mean_p = jnp.mean(probs, axis=tuple(range(probs.ndim - 1)))
+    return cfg.num_experts * jnp.sum(frac * mean_p)
 
 
-def apply_moe(cfg: ModelConfig, p, x, ep_spec=None):
-    """Capacity-dispatch MoE FFN. x: (B, S, d) — one group per batch row;
-    long sequences are split into ``moe_group_size`` routing sub-groups so
-    capacity buffers stay bounded (32k-prefill would otherwise materialize
-    (B, E, 5120, d) dispatch buffers). ``ep_spec``: PartitionSpec for the
-    (groups, E, C, d) dispatch buffers — expert dim on "model" keeps them
-    expert-parallel instead of replicated."""
-    B, S, d = x.shape
-    if S == 1:
-        return _apply_moe_decode(cfg, p, x)
-    gs = cfg.moe_group_size
-    if S > gs and S % gs == 0:
-        n = S // gs
-        out = _apply_moe_grouped(cfg, p, x.reshape(B * n, gs, d), ep_spec)
-        return out.reshape(B, S, d)
-    return _apply_moe_grouped(cfg, p, x, ep_spec)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def take_rows(a, idx, back, fold: int):
+    """``a[idx]``: rows gathered into a new order. ``back`` inverts the
+    gather: ``idx[back]`` is each row of ``a`` repeated ``fold`` times. The
+    gradient is then the gather ``g[back]`` summed over each ``fold``
+    consecutive rows, so neither pass moves a row by scatter-add."""
+    return jnp.take(a, idx, axis=0, mode="clip")
 
 
-def _apply_moe_grouped(cfg: ModelConfig, p, x, ep_spec=None):
-    from jax.sharding import PartitionSpec as P
-    B, S, d = x.shape
-    E, k = cfg.num_experts, cfg.experts_per_token
-    C = capacity(cfg, S)
-    tok_spec = P(ep_spec[0], None, None) if ep_spec is not None else None
-    if tok_spec is not None:
-        # pin the dispatch gather to batch-sharded/d-replicated — without
-        # this GSPMD (with a pod axis present) shards the gather's d-dim over
-        # "model" and then fully rematerializes to reshard (observed: 64 GiB)
-        x = jax.lax.with_sharding_constraint(x, tok_spec)
-    top_w, top_e = route(cfg, p, x)                                # (B,S,k)
+def _take_rows_fwd(a, idx, back, fold):
+    return jnp.take(a, idx, axis=0, mode="clip"), back
 
-    gathered, slot_token, keep_w, slot = jax.vmap(
-        lambda xg, wg, eg: _dispatch_group(cfg, xg, wg, eg, C)
-    )(x, top_w, top_e)
-    if tok_spec is not None:
-        gathered = jax.lax.with_sharding_constraint(gathered, tok_spec)
-    expert_in = gathered.reshape(B, E, C, d)
-    if ep_spec is not None:
-        expert_in = jax.lax.with_sharding_constraint(expert_in, ep_spec)
 
-    act = jax.nn.gelu if cfg.act == "gelu" else jax.nn.silu
-    h = jnp.einsum("becd,edf->becf", expert_in, p["w_in"].astype(x.dtype))
+def _take_rows_bwd(fold, back, g):
+    ga = jnp.take(g, back, axis=0, mode="clip")
+    if fold > 1:
+        ga = ga.reshape(-1, fold, g.shape[-1]).astype(jnp.float32).sum(1)
+    return ga.astype(g.dtype), None, None
+
+
+take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def sort_by_expert(top_e):
+    """The T * k pairs (token-major, as ``top_e.reshape(-1)``) in expert
+    order: (order, inv), ``order[r]`` the pair at sorted row r and ``inv``
+    its inverse."""
+    order = jnp.argsort(top_e.reshape(-1), stable=True)
+    return order, jnp.argsort(order)
+
+
+def expert_sizes(cfg: ModelConfig, top_e):
+    """Routed pairs per expert, (E,) int32."""
+    return jnp.sum(top_e.reshape(-1, 1) == jnp.arange(cfg.num_experts),
+                   axis=0, dtype=jnp.int32)
+
+
+def _act(cfg: ModelConfig):
+    return jax.nn.gelu if cfg.act == "gelu" else jax.nn.silu
+
+
+def grouped_ffn(cfg: ModelConfig, p, rows, sizes):
+    """Expert FFN of rows sorted by expert, ``sizes[e]`` rows for expert e:
+    (M, d) -> (M, d), each row through its own expert's weights."""
+    dt = rows.dtype
+    h = jax.lax.ragged_dot(rows, p["w_in"].astype(dt), sizes)
     if cfg.glu:
-        g = jnp.einsum("becd,edf->becf", expert_in, p["w_gate"].astype(x.dtype))
-        h = act(g) * h
+        g = jax.lax.ragged_dot(rows, p["w_gate"].astype(dt), sizes)
+        h = _act(cfg)(g) * h
     else:
-        h = act(h)
-    out_e = jnp.einsum("becf,efd->becd", h, p["w_out"].astype(x.dtype))
-    out_e = out_e.reshape(B, E * C, d)
-
-    # combine: out[s] += w[s,j] * out_e[slot[s,j]]
-    def _combine(out_eg, slot_g, w_g):
-        out_pad = jnp.concatenate([out_eg, jnp.zeros((1, d), out_eg.dtype)], axis=0)
-        sel = jnp.take(out_pad, jnp.minimum(slot_g, E * C), axis=0)  # (S,k,d)
-        return jnp.einsum("skd,sk->sd", sel, w_g.astype(out_eg.dtype))
-    return jax.vmap(_combine)(out_e, slot, keep_w)
+        h = _act(cfg)(h)
+    return jax.lax.ragged_dot(h, p["w_out"].astype(dt), sizes)
 
 
-def _apply_moe_decode(cfg: ModelConfig, p, x):
+def apply_moe(cfg: ModelConfig, p, x):
+    """MoE FFN of x (B, S, d). Returns (out, aux, load): out (B, S, d); aux
+    the load-balance loss; load the most-loaded expert's routed rows over
+    the mean (1 when balanced)."""
+    B, S, d = x.shape
+    k, E = cfg.experts_per_token, cfg.num_experts
+    probs, top_w, top_e = route(cfg, p, x)
+    aux = load_balance_loss(cfg, probs, top_e)
+    sizes = expert_sizes(cfg, top_e)
+    load = jnp.max(sizes).astype(jnp.float32) * E / (B * S * k)
+    if S == 1:
+        return _apply_moe_decode(cfg, p, x, top_w, top_e), aux, load
+    order, inv = sort_by_expert(top_e)
+    xt = x.reshape(B * S, d)
+    rows = take_rows(xt, order // k, inv, k)                 # (T*k, d)
+    out = grouped_ffn(cfg, p, rows, sizes)
+    y = take_rows(out, inv, order, 1).reshape(B * S, k, d)  # token order
+    y = jnp.sum(y.astype(jnp.float32) * top_w.reshape(B * S, k, 1), axis=1)
+    return y.astype(x.dtype).reshape(B, S, d), aux, load
+
+
+def _apply_moe_decode(cfg: ModelConfig, p, x, top_w, top_e):
     """Dense-all-experts decode path (weight-bandwidth optimal)."""
-    top_w, top_e = route(cfg, p, x)                                # (B,1,k)
     # dense per-token expert weights: sum_j w_j * onehot(e_j)
     w_full = jnp.sum(
         top_w[..., None] * jax.nn.one_hot(top_e, cfg.num_experts,
                                           dtype=jnp.float32), axis=-2)
-    act = jax.nn.gelu if cfg.act == "gelu" else jax.nn.silu
+    act = _act(cfg)
     h = jnp.einsum("bsd,edf->besf", x, p["w_in"].astype(x.dtype))
     if cfg.glu:
         g = jnp.einsum("bsd,edf->besf", x, p["w_gate"].astype(x.dtype))
@@ -155,15 +161,3 @@ def _apply_moe_decode(cfg: ModelConfig, p, x):
         h = act(h)
     out_e = jnp.einsum("besf,efd->besd", h, p["w_out"].astype(x.dtype))
     return jnp.einsum("besd,bse->bsd", out_e, w_full.astype(x.dtype))
-
-
-def load_balance_loss(cfg: ModelConfig, p, x) -> jnp.ndarray:
-    """Auxiliary load-balancing loss (Switch-style): E * sum(f_e * p_e)."""
-    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    _, top_e = jax.lax.top_k(probs, cfg.experts_per_token)
-    frac = jnp.mean(jax.nn.one_hot(top_e, cfg.num_experts, dtype=jnp.float32),
-                    axis=tuple(range(top_e.ndim)))
-    mean_p = jnp.mean(probs, axis=tuple(range(probs.ndim - 1)))
-    return cfg.num_experts * jnp.sum(frac * mean_p)

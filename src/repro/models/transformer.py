@@ -109,9 +109,17 @@ def init_decoder_only(cfg: ModelConfig, key, pol: ShardingPolicy, env: AxisEnv,
 # ---------------------------------------------------------------------------
 # layer bodies (shared by train / prefill / decode)
 # ---------------------------------------------------------------------------
+def _residual(cfg: ModelConfig, y):
+    """A block's output as the residual stream takes it."""
+    if cfg.residual_multiplier is None:
+        return y
+    return y * cfg.residual_multiplier
+
+
 def _attn_mlp_layer(cfg: ModelConfig, lp, x, positions, cache=None,
-                    cache_pos=None, ep_spec=None):
-    """Standard pre-norm block. Returns (x, new_kv_or_None, aux_loss)."""
+                    cache_pos=None):
+    """Standard pre-norm block. Returns (x, new_kv_or_None, (aux_loss,
+    moe_load)); both are 0 in a dense block."""
     h = nn.apply_norm(cfg, lp, "norm1", x)
     if cache is None:
         a, (k, v) = attn.self_attention(cfg, lp, h, positions)
@@ -121,22 +129,14 @@ def _attn_mlp_layer(cfg: ModelConfig, lp, x, positions, cache=None,
         a, ck, cv = attn.decode_self_attention(cfg, lp, h, ck, cv, cache_pos,
                                                positions)
         new_kv = (ck, cv)
-    x = x + a
+    x = x + _residual(cfg, a)
     h = nn.apply_norm(cfg, lp, "norm2", x)
     if cfg.family == MOE:
-        f = moe_mod.apply_moe(cfg, lp, h, ep_spec=ep_spec)
-        aux = moe_mod.load_balance_loss(cfg, lp, h)
+        f, aux, load = moe_mod.apply_moe(cfg, lp, h)
     else:
         f = nn.apply_mlp(cfg, lp, h)
-        aux = jnp.zeros((), jnp.float32)
-    return x + f, new_kv, aux
-
-
-def moe_ep_spec(env: AxisEnv, pol: ShardingPolicy, batch: int):
-    """Dispatch-buffer spec (groups, E, C, d): experts on the model axis."""
-    if pol.experts_sharded:
-        return P(env.batch_axes(batch), env.tp, None, None)
-    return None
+        aux = load = jnp.zeros((), jnp.float32)
+    return x + _residual(cfg, f), new_kv, (aux, load)
 
 
 def _ssm_layer(cfg: ModelConfig, lp, x, cache=None):
@@ -171,24 +171,29 @@ def _embed_input(cfg: ModelConfig, params, batch) -> Tuple[jnp.ndarray, Any]:
 
 def forward_decoder_only(cfg: ModelConfig, params, batch, env: AxisEnv,
                          pol: ShardingPolicy, *, return_cache: bool = False,
-                         last_token_only: bool = False):
-    """Full-sequence forward. Returns (logits, aux_loss, cache_or_None)."""
+                         last_token_only: bool = False, stats: bool = False):
+    """Full-sequence forward. Returns (logits, aux_loss, cache_or_None), and
+    with ``stats`` a fourth item: a dict of what a step reports beside its
+    loss (``moe_load_max``, the largest over layers of the most-loaded
+    expert's routed rows over the mean, for a MoE model)."""
     x, positions = _embed_input(cfg, params, batch)
     B = x.shape[0]
     x = constrain(x, env, pol, B)
     lp_all = params["layers"]
+    out_stats = {}
 
     if cfg.family in (DENSE, MOE, VLM):
-        ep = moe_ep_spec(env, pol, B) if cfg.family == MOE else None
-
         def body(x, lp):
             x = checkpoint_name(x, "layer_act")
-            x2, kv, aux = _attn_mlp_layer(cfg, lp, x, positions, ep_spec=ep)
+            x2, kv, aux = _attn_mlp_layer(cfg, lp, x, positions)
             x2 = constrain(x2, env, pol, B)
             ys = (kv if return_cache else None, aux)
             return x2, ys
-        x, (kvs, auxs) = jax.lax.scan(remat_wrap(cfg, body), x, lp_all)
+        x, (kvs, (auxs, loads)) = jax.lax.scan(remat_wrap(cfg, body), x,
+                                               lp_all)
         aux = jnp.sum(auxs)
+        if cfg.family == MOE:
+            out_stats["moe_load_max"] = jnp.max(loads)
         cache = None
         if return_cache:
             cache = {"k": kvs[0], "v": kvs[1]}  # (L, B, S, KV, hd)
@@ -213,6 +218,8 @@ def forward_decoder_only(cfg: ModelConfig, params, batch, env: AxisEnv,
         x = x[:, -1:, :]  # prefill: only the next-token logits are needed
     logits = nn.unembed(cfg, params, x,
                         seq_shard_spec=unembed_spec(env, pol, B))
+    if stats:
+        return logits, aux, cache, out_stats
     return logits, aux, cache
 
 

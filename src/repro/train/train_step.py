@@ -30,18 +30,14 @@ class TrainStepConfig:
     opt: adamw.AdamWConfig = adamw.AdamWConfig()
 
 
-def make_loss_fn(model: Model):
-    def loss_fn(params, batch):
-        return model.loss_fn(params, batch)
-    return loss_fn
-
-
 def _accumulate_grads(model: Model, params, batch, microbatches: int):
-    """lax.scan over microbatches; batch leading dim must divide evenly."""
-    loss_fn = make_loss_fn(model)
+    """lax.scan over microbatches; batch leading dim must divide evenly.
+    Returns (loss, grads, stats), each stat (a largest value, as
+    ``moe_load_max``) the largest over the microbatches."""
+    grad_fn = jax.value_and_grad(model.loss_and_stats, has_aux=True)
     if microbatches <= 1:
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        return loss, grads
+        (loss, stats), grads = grad_fn(params, batch)
+        return loss, grads, stats
 
     def reshape(x, axis=0):
         b = x.shape[axis]
@@ -56,15 +52,17 @@ def _accumulate_grads(model: Model, params, batch, microbatches: int):
 
     def body(carry, one):
         loss_acc, grads_acc = carry
-        loss, grads = jax.value_and_grad(loss_fn)(params, one)
+        (loss, stats), grads = grad_fn(params, one)
         grads_acc = jax.tree_util.tree_map(jnp.add, grads_acc, grads)
-        return (loss_acc + loss, grads_acc), None
+        return (loss_acc + loss, grads_acc), stats
 
     zeros = jax.tree_util.tree_map(
         lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    (loss, grads), _ = jax.lax.scan(body, (jnp.zeros((), jnp.float32), zeros), mb)
+    (loss, grads), stats = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32), zeros), mb)
     scale = 1.0 / microbatches
-    return loss * scale, jax.tree_util.tree_map(lambda g: g * scale, grads)
+    return (loss * scale, jax.tree_util.tree_map(lambda g: g * scale, grads),
+            jax.tree_util.tree_map(lambda s: jnp.max(s, axis=0), stats))
 
 
 def make_train_step(model: Model, mesh, cfg: TrainStepConfig,
@@ -81,8 +79,7 @@ def make_train_step(model: Model, mesh, cfg: TrainStepConfig,
     opt_sh = adamw.AdamWState(step=NamedSharding(mesh, P()),
                               mu=sh(param_specs), nu=sh(param_specs))
     batch_sh = sh(batch_specs)
-    metrics_sh = jax.tree_util.tree_map(
-        lambda _: NamedSharding(mesh, P()), {"loss": 0, "grad_norm": 0, "lr": 0})
+    metrics_sh = NamedSharding(mesh, P())    # every metric is a scalar
 
     if compress:
         # NOTE (documented limitation, EXPERIMENTS §Dry-run): ideally the
@@ -96,12 +93,12 @@ def make_train_step(model: Model, mesh, cfg: TrainStepConfig,
         # is verified to cut cross-pod bytes 4× in isolation
         # (tests/test_sharding.py::test_compressed_grad_sync_reduces_dcn_bytes).
         def step(params, opt_state, batch, err):
-            loss, grads = _accumulate_grads(model, params, batch,
-                                            cfg.microbatches)
+            loss, grads, stats = _accumulate_grads(model, params, batch,
+                                                   cfg.microbatches)
             grads, err = cross_pod_sync(grads, err, mesh, compress=True)
             new_params, new_opt, metrics = adamw.update(cfg.opt, grads,
                                                         opt_state, params)
-            metrics["loss"] = loss
+            metrics.update(stats, loss=loss)
             return new_params, new_opt, metrics, err
 
         jit_step = jax.jit(
@@ -111,11 +108,11 @@ def make_train_step(model: Model, mesh, cfg: TrainStepConfig,
             donate_argnums=(0, 1, 3))
     else:
         def step(params, opt_state, batch):
-            loss, grads = _accumulate_grads(model, params, batch,
-                                            cfg.microbatches)
+            loss, grads, stats = _accumulate_grads(model, params, batch,
+                                                   cfg.microbatches)
             new_params, new_opt, metrics = adamw.update(cfg.opt, grads,
                                                         opt_state, params)
-            metrics["loss"] = loss
+            metrics.update(stats, loss=loss)
             return new_params, new_opt, metrics
 
         jit_step = jax.jit(

@@ -1,5 +1,6 @@
 """Prefill + single-token decode must equal the full forward pass — per
-family, including MoE (with no capacity drops) and the SSM/hybrid states."""
+family, including MoE (dropless, so prefill routes as the full pass does)
+and the SSM/hybrid states."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -20,7 +21,7 @@ ARCHS = ["llama3-8b", "qwen3-32b", "starcoder2-7b", "phi3-mini-3.8b",
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_prefill_decode_matches_forward(arch):
-    cfg = get_config(arch).reduced().with_(remat="none", capacity_factor=8.0)
+    cfg = get_config(arch).reduced().with_(remat="none")
     model = build_model(cfg, ENV)
     params, _ = model.init(jax.random.PRNGKey(1))
     full = model.synthetic_batch(ShapeSuite("f", PREFILL, S_P + 1, B),

@@ -340,15 +340,21 @@ def test_ssd_kernel_agrees_with_model_ssd():
 
 
 # ---------------------------------------------------------------------------
-# grouped matmul / stream matmul
+# grouped products / stream matmul
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("E,C,d,f", [(2, 128, 128, 128), (4, 256, 128, 384),
                                      (1, 128, 256, 128)])
 def test_gmm_matches_ref(E, C, d, f):
+    """The MoE layer's grouped products (``jax.lax.ragged_dot``) over E * C
+    rows in groups of uneven size, the last group left empty where there
+    are several, against the loop over the groups."""
     ks = jax.random.split(jax.random.PRNGKey(4), 2)
-    x = jax.random.normal(ks[0], (E, C, d), jnp.float32)
+    x = jax.random.normal(ks[0], (E * C, d), jnp.float32)
     w = jax.random.normal(ks[1], (E, d, f), jnp.float32)
-    assert _rel_err(ops.grouped_matmul(x, w), ref.gmm_ref(x, w)) < 1e-5
+    sizes = [E * C] if E == 1 else [C + C // 2] + [C // 2] * (E - 2) + [0]
+    sizes[0] += E * C - sum(sizes)
+    got = jax.lax.ragged_dot(x, w, jnp.asarray(sizes, jnp.int32))
+    assert _rel_err(got, ref.gmm_ref(x, w, sizes)) < 1e-5
 
 
 @pytest.mark.parametrize("M,K,N,bk", [(128, 512, 128, 256), (256, 1024, 384, 512)])

@@ -20,7 +20,7 @@ from jax.sharding import Mesh, PartitionSpec as P, SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import flash_attention as fa
-from repro.kernels import moe_gmm, ops, ssd_scan, stream_matmul
+from repro.kernels import ops, ssd_scan, stream_matmul
 from repro.models import attention
 from repro.models.common import host_axis_env
 from repro.models.model_zoo import build_model
@@ -141,10 +141,52 @@ def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
 
 
 def test_grouped_matmul_compiles_at_granite_moe_widths(one_chip):
+    """The MoE layer's grouped products at the training cell's widths,
+    forward and backward: 4 x 4096 tokens routed to 8 of 32 experts, all
+    in XLA's ragged-dot kernels."""
     cfg = get_config("granite-moe-1b-a400m")
     e, d, f = cfg.num_experts, cfg.d_model, cfg.d_ff
-    _compile_kernel(moe_gmm.grouped_matmul,
-                    _shapes(one_chip, (e, 256, d), (e, d, f)))
+    rows = 4 * 4096 * cfg.experts_per_token
+    sizes = jax.ShapeDtypeStruct((e,), jnp.int32, sharding=one_chip)
+
+    def fwd_bwd(x, w, dy, sizes):
+        out, vjp = jax.vjp(lambda x, w: jax.lax.ragged_dot(x, w, sizes), x, w)
+        return out, vjp(dy)
+    compiled = _compile_kernel(
+        fwd_bwd, [*_shapes(one_chip, (rows, d), (e, d, f), (rows, f)), sizes])
+    assert compiled.as_text().count("ragged-dot") >= 3
+
+
+def test_granite_train_step_fits_one_chip(one_chip, monkeypatch):
+    """The granite-moe-1b-a400m train step at the benchmark cell's sizes,
+    8 of 24 layers and 4 x 4096 tokens, float32 parameters and AdamW state
+    with bf16 compute, attention dispatched as on one TPU device: the
+    grouped products and the fused attention are in the program, and it
+    fits a 16 GB v5e chip."""
+    monkeypatch.setattr(attention, "_on_one_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    cfg = get_config("granite-moe-1b-a400m").with_(
+        num_layers=8, param_dtype="float32", dtype="bfloat16", remat="layer")
+    model = build_model(cfg, mesh)
+    step, sh = make_train_step(model, mesh, TrainStepConfig(),
+                               {"tokens": P(), "labels": P()})
+    place = lambda tree, shard: jax.tree_util.tree_map(  # noqa: E731
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, shard)
+    params = place(model.init(None, abstract=True)[0], sh["params"])
+    opt = place(jax.eval_shape(adamw.init, params), sh["opt"])
+    tokens = jax.ShapeDtypeStruct((4, 4096), jnp.int32,
+                                  sharding=sh["batch"]["tokens"])
+    compiled = step.lower(params, opt, {"tokens": tokens,
+                                        "labels": tokens}).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "flash_attention_bwd_dkv" in text
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < V5E_HBM_BYTES, used
 
 
 def test_stream_matmul_compiles_at_phi3_mlp_widths(one_chip):

@@ -53,8 +53,8 @@ def test_microbatch_grads_match_full_batch():
     # fp32 activations so the only difference is summation order
     cfg, model, params = _tiny_model(remat="none", dtype="float32")
     batch = model.synthetic_batch(ShapeSuite("t", TRAIN, 32, 4))
-    loss1, g1 = _accumulate_grads(model, params, batch, 1)
-    loss4, g4 = _accumulate_grads(model, params, batch, 4)
+    loss1, g1, _ = _accumulate_grads(model, params, batch, 1)
+    loss4, g4, _ = _accumulate_grads(model, params, batch, 4)
     # microbatch mean-of-means == full mean (equal microbatch sizes)
     np.testing.assert_allclose(float(loss1), float(loss4), rtol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(g1),
