@@ -7,6 +7,11 @@
 * ``flash_attention`` — forward only, (B, S, H, hd) (``attn_impl="pallas"``).
 * ``flash_attention_grads`` — out and (dq, dk, dv) in the folded (BH, S, hd)
   layout, for tests.
+* ``moe_route``, ``moe_dispatch``, ``moe_combine`` — the MoE layer's row
+  permute: the dispatch into expert order (XLA's gather) and the weighted
+  combine back (``kernels/moe_permute.py``), custom VJPs whose backward
+  passes are that file's kernels; ``models.moe.apply_moe`` runs them for
+  S > 1.
 * ``ssd``, ``stream_matmul``.
 
 On the CPU backend the wrappers run the kernels in interpret mode (Python
@@ -16,12 +21,14 @@ else they compile for the device.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
+from repro.kernels import moe_permute as _mp
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import stream_matmul as _sm
 
@@ -115,6 +122,83 @@ def flash_attention_grads(q, k, v, dout, *, causal: bool = True,
         q, k, v, out, lse, dout, causal=causal, block_q=block_q,
         block_k=block_k, interpret=interp)
     return out, dq, dk, dv
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["tok", "order", "inv", "plan"],
+                   meta_fields=["k"])
+@dataclasses.dataclass(frozen=True)
+class MoERoute:
+    """Where the T * k (token, expert) pairs go: ``order[r]`` the pair
+    (token-major) at sorted row r, ``inv`` its inverse, ``tok[r]`` the
+    token of row r, and the combine's copy plan."""
+    tok: jnp.ndarray
+    order: jnp.ndarray
+    inv: jnp.ndarray
+    plan: _mp.CombinePlan
+    k: int
+
+
+def moe_route(top_e, order, inv, num_experts: int, dtype) -> MoERoute:
+    """The route of ``top_e`` (T, k) sorted by ``order`` (``inv`` its
+    inverse), for rows of ``dtype``."""
+    k = top_e.shape[-1]
+    return MoERoute(order // k, order, inv,
+                    _mp.combine_plan(top_e, inv, num_experts, dtype), k)
+
+
+def _padded(x):
+    """x with its rows padded to a width the kernels take."""
+    d = x.shape[-1]
+    return jnp.pad(x, ((0, 0), (0, _mp.pad_dim(d, x.dtype) - d)))
+
+
+@jax.custom_vjp
+def moe_dispatch(x, route: MoERoute):
+    """x (T, d) -> its rows in expert order, (T * k, d): row r is
+    ``x[route.tok[r]]``. XLA's gather: from a (T, d) source it runs near
+    the bandwidth, faster than a kernel that first flattens the source."""
+    return jnp.take(x, route.tok, axis=0, mode="clip")
+
+
+def _dispatch_fwd(x, route):
+    return moe_dispatch(x, route), route
+
+
+def _dispatch_bwd(route, g):
+    # each token's k rows summed back: a combine with unit weights
+    T = route.tok.shape[0] // route.k
+    dx = _mp.combine(_padded(g), jnp.ones((T, route.k), jnp.float32),
+                     route.plan, interpret=_interpret())
+    return dx[:, :g.shape[-1]], None
+
+
+moe_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def moe_combine(rows, w, route: MoERoute):
+    """Rows in expert order (T * k, d) and weights w (T, k) float32 -> y
+    (T, d), y[t] = sum_j w[t, j] * rows[route.inv[t * k + j]], summed in
+    float32 and cast to the rows' dtype."""
+    y = _mp.combine(_padded(rows), w, route.plan, interpret=_interpret())
+    return y[:, :rows.shape[-1]]
+
+
+def _combine_fwd(rows, w, route):
+    return moe_combine(rows, w, route), (rows, w, route)
+
+
+def _combine_bwd(res, g):
+    rows, w, route = res
+    d = rows.shape[-1]
+    w_sorted = w.reshape(-1)[route.order]
+    drows, dw_sorted = _mp.combine_bwd(_padded(g), _padded(rows), route.tok,
+                                       w_sorted, interpret=_interpret())
+    return drows[:, :d], dw_sorted[route.inv].reshape(w.shape), None
+
+
+moe_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "nh_block"))

@@ -9,27 +9,31 @@ Training and prefill (S > 1), over the T = B * S tokens of a call:
   3. the expert FFN runs as grouped products over the sorted rows
      (``jax.lax.ragged_dot``, group e being expert e's ``sizes[e]`` rows);
      on a TPU, XLA's ragged-dot kernel;
-  4. the rows are gathered back into token order and each token's k
-     outputs are summed with its weights.
+  4. the combine kernel gathers each token's k output rows back and sums
+     them with its weights in float32, so no (T * k, d) array in token
+     order is written.
 
-No pair is dropped. The device work is set by the shapes, not by where the
-tokens go: the row moves are gathers of T * k rows whose gradients are the
-inverse permutation's gathers (never a scatter-add), the sort is over a
-fixed count of keys, and the grouped products cover all T * k rows however
-they fall into groups.
+The moves are custom VJPs in ``kernels/ops.py``: the dispatch is XLA's
+gather from the small (T, d) input; the combine and both transposes are the
+Pallas kernels of ``kernels/moe_permute.py`` (interpret mode on the CPU):
+the dispatch's transpose is a combine with unit weights, the combine's a
+gather that scales each row. No pair is dropped. The device work is set
+by the shapes, not by where the tokens go: every pass moves all T * k rows
+and none scatters, the sort is over a fixed count of keys, and the grouped
+products cover all T * k rows however they fall into groups.
 
 Decode (S == 1) is weight-bandwidth-bound: every expert runs on the token
 and the routing weights combine them.
 """
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from repro.models.common import ParamBuilder
 
 
@@ -69,29 +73,6 @@ def load_balance_loss(cfg: ModelConfig, probs, top_e) -> jnp.ndarray:
                     axis=tuple(range(top_e.ndim)))
     mean_p = jnp.mean(probs, axis=tuple(range(probs.ndim - 1)))
     return cfg.num_experts * jnp.sum(frac * mean_p)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def take_rows(a, idx, back, fold: int):
-    """``a[idx]``: rows gathered into a new order. ``back`` inverts the
-    gather: ``idx[back]`` is each row of ``a`` repeated ``fold`` times. The
-    gradient is then the gather ``g[back]`` summed over each ``fold``
-    consecutive rows, so neither pass moves a row by scatter-add."""
-    return jnp.take(a, idx, axis=0, mode="clip")
-
-
-def _take_rows_fwd(a, idx, back, fold):
-    return jnp.take(a, idx, axis=0, mode="clip"), back
-
-
-def _take_rows_bwd(fold, back, g):
-    ga = jnp.take(g, back, axis=0, mode="clip")
-    if fold > 1:
-        ga = ga.reshape(-1, fold, g.shape[-1]).astype(jnp.float32).sum(1)
-    return ga.astype(g.dtype), None, None
-
-
-take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 def sort_by_expert(top_e):
@@ -138,12 +119,11 @@ def apply_moe(cfg: ModelConfig, p, x):
     if S == 1:
         return _apply_moe_decode(cfg, p, x, top_w, top_e), aux, load
     order, inv = sort_by_expert(top_e)
-    xt = x.reshape(B * S, d)
-    rows = take_rows(xt, order // k, inv, k)                 # (T*k, d)
+    perm = ops.moe_route(top_e.reshape(B * S, k), order, inv, E, x.dtype)
+    rows = ops.moe_dispatch(x.reshape(B * S, d), perm)           # (T*k, d)
     out = grouped_ffn(cfg, p, rows, sizes)
-    y = take_rows(out, inv, order, 1).reshape(B * S, k, d)  # token order
-    y = jnp.sum(y.astype(jnp.float32) * top_w.reshape(B * S, k, 1), axis=1)
-    return y.astype(x.dtype).reshape(B, S, d), aux, load
+    y = ops.moe_combine(out, top_w.reshape(B * S, k), perm)      # (T, d)
+    return y.reshape(B, S, d), aux, load
 
 
 def _apply_moe_decode(cfg: ModelConfig, p, x, top_w, top_e):

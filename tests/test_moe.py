@@ -1,8 +1,10 @@
 """The dropless MoE layer at a small size (width 64, 4 experts, top-2):
-its grouped products against a loop over the experts, the layer and the
-whole model against the plain float32 reference of the benchmark
+its row permute kernels against gathers by ``jnp.take``, its grouped
+products against a loop over the experts, the layer and the whole model
+against the plain float32 reference of the benchmark
 (``benchmarks/chip/reference/moe_decoder.py``), and routing whose device
 work does not depend on where the tokens go."""
+import functools
 import json
 from pathlib import Path
 
@@ -11,9 +13,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.experimental.pallas import tpu as pltpu
+
 from benchmarks.chip.reference import moe_decoder as md
 from repro.configs import get_config
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.models import moe
 from repro.models.common import host_axis_env
 from repro.models.model_zoo import build_model
@@ -78,6 +82,63 @@ def test_grouped_products_match_a_loop_over_experts(sizes):
     # an expert with no rows gets no gradient
     for e in np.flatnonzero(np.asarray(sizes) == 0):
         assert not np.any(np.asarray(vjp(dy)[0]["w_in"][e]))
+
+
+def _routing(kind, T):
+    """(top_w, top_e) of T tokens: every pair to one expert (top-1), two of
+    four experts left empty (top-2), or spread over eight (top-4)."""
+    ks = jax.random.split(jax.random.PRNGKey(9), 2)
+    if kind == "one-expert":
+        return jnp.ones((T, 1), jnp.float32), jnp.zeros((T, 1), jnp.int32)
+    k, E = (2, 4) if kind == "empty-experts" else (4, 8)
+    logits = jax.random.normal(ks[0], (T, E))
+    if kind == "empty-experts":
+        logits = logits.at[:, ::2].add(-100.0)
+    w, e = jax.lax.top_k(jax.nn.softmax(logits), k)
+    return w / jnp.sum(w, -1, keepdims=True), e.astype(jnp.int32)
+
+
+@pytest.mark.parametrize("d", [64, 256])
+@pytest.mark.parametrize("kind", ["one-expert", "empty-experts", "spread"])
+def test_permute_kernels_match_take(kind, d, monkeypatch):
+    """The row permute, its kernels in TPU interpret mode (DMAs and
+    semaphores emulated), against ``jnp.take`` and the float32 weighted
+    sum: the dispatch bit for bit, the combine within float32 rounding, and
+    both gradients (the combine kernel and its transpose) against
+    ``jax.vjp`` of the gathers. 24 tokens fill no whole block, so the
+    padding paths run too."""
+    monkeypatch.setattr(ops, "_interpret", pltpu.InterpretParams)
+    T = 24
+    top_w, top_e = _routing(kind, T)
+    k = top_e.shape[1]
+    E = max(int(jnp.max(top_e)) + 1, 4)
+    order, inv = moe.sort_by_expert(top_e)
+    ks = jax.random.split(jax.random.PRNGKey(d), 3)
+    x = jax.random.normal(ks[0], (T, d), jnp.float32)
+    out = jax.random.normal(ks[1], (T * k, d), jnp.float32)
+
+    def kernels(x, out, w):
+        perm = ops.moe_route(top_e, order, inv, E, x.dtype)
+        return ops.moe_dispatch(x, perm), ops.moe_combine(out, w, perm)
+
+    def takes(x, out, w):
+        y = jnp.take(out, inv, axis=0).reshape(T, k, d)
+        return (jnp.take(x, order // k, axis=0),
+                jnp.sum(y * w[..., None], axis=1))
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def both(f, cot):
+        got, vjp = jax.vjp(f, x, out, top_w)
+        return got, vjp(cot)
+
+    cot = (jax.random.normal(ks[2], (T * k, d)),
+           jnp.cos(jnp.arange(T * d, dtype=jnp.float32)).reshape(T, d))
+    (rows, y), grads = both(kernels, cot)
+    (want_rows, want_y), want_grads = both(takes, cot)
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(want_rows))
+    np.testing.assert_allclose(y, want_y, rtol=1e-6, atol=1e-6)
+    for name, a, b in zip(["x", "out", "w"], grads, want_grads):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5, err_msg=name)
 
 
 def _skewed_layer(s, seed=5):

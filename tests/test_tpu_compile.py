@@ -157,12 +157,37 @@ def test_grouped_matmul_compiles_at_granite_moe_widths(one_chip):
     assert compiled.as_text().count("ragged-dot") >= 3
 
 
+def test_moe_permute_compiles_at_granite_moe_widths(one_chip, monkeypatch):
+    """The MoE row permute at the training cell's widths, forward and
+    backward: 4 x 4096 tokens of d = 1024 in bf16, each routed to 8 of 32
+    experts; the combine, its transpose and the flattening it reads."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = get_config("granite-moe-1b-a400m")
+    T, d, k, E = 4 * 4096, cfg.d_model, cfg.experts_per_token, cfg.num_experts
+
+    def fwd_bwd(x, w, top_e, order, inv, dy):
+        perm = ops.moe_route(top_e, order, inv, E, x.dtype)
+
+        def layer(x, w):
+            rows = ops.moe_dispatch(x, perm)
+            return ops.moe_combine(rows * 2, w, perm)
+        y, vjp = jax.vjp(layer, x, w)
+        return y, vjp(dy)
+    args = [*_shapes(one_chip, (T, d)), *_shapes(one_chip, (T, k),
+                                                 dtype=jnp.float32),
+            *_shapes(one_chip, (T, k), (T * k,), (T * k,), dtype=jnp.int32),
+            *_shapes(one_chip, (T, d))]
+    text = _compile_kernel(fwd_bwd, args).as_text()
+    for kernel in ("flatten", "combine", "combine_bwd"):
+        assert f"moe_permute_{kernel}" in text, kernel
+
+
 def test_granite_train_step_fits_one_chip(one_chip, monkeypatch):
     """The granite-moe-1b-a400m train step at the benchmark cell's sizes,
     8 of 24 layers and 4 x 4096 tokens, float32 parameters and AdamW state
     with bf16 compute, attention dispatched as on one TPU device: the
-    grouped products and the fused attention are in the program, and it
-    fits a 16 GB v5e chip."""
+    grouped products, the row permute kernels and the fused attention are
+    in the program, and it fits a 16 GB v5e chip."""
     monkeypatch.setattr(attention, "_on_one_tpu", lambda: True)
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
@@ -183,6 +208,7 @@ def test_granite_train_step_fits_one_chip(one_chip, monkeypatch):
                                         "labels": tokens}).compile()
     text = compiled.as_text()
     assert "ragged-dot" in text and "flash_attention_bwd_dkv" in text
+    assert "moe_permute_" in text
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
