@@ -258,8 +258,7 @@ def run() -> None:
              f"queue_s={rec.place_s - rec.job.arrival_s:.0f}")
 
     # seeded mixed trace, heavier than the CLI default so queues form;
-    # run both engines — frozen (PR 2 compatibility) and progress-based
-    # (every admission/completion re-solves the shared-cap throttle)
+    # every admission/completion re-solves the shared-cap throttle
     trace = generate_trace(TraceConfig(seed=0, n_jobs=48,
                                        mean_interarrival_s=5.0))
     for policy in POLICY_NAMES:
@@ -270,11 +269,6 @@ def run() -> None:
              f"queue_p95={m.p95_queue_delay_s:.0f}s "
              f"energy_MJ={m.energy_J / 1e6:.0f} repacks={m.repacks} "
              f"power_deferrals={m.power_deferrals}")
-    _, mf, us = _run("frag_repack", trace, n_pods=1, frozen_durations=True)
-    emit("cluster/trace0.frozen-vs-progress", us,
-         f"frozen_makespan={mf.makespan_s:.0f}s "
-         f"frozen_slo={mf.slo_attainment:.2f} "
-         f"frozen_energy_MJ={mf.energy_J / 1e6:.0f}")
 
 
 # the committed-baseline regime: 8 pods keep a 12s-interarrival Poisson

@@ -945,7 +945,6 @@ class Shrink(Action):
         pod.sim.resize(victim.job.job_id, small.profile.n_chips,
                        victim.u_compute, small.step_time)
         t_mig = sched._charge_migration(pod, moved_bytes, [victim], t)
-        sched._reissue_after_resize(pod, victim, t)
         cand = candidate_on(pod, self.rec.job, sc, t, self.rec.deadline_s)
         assert cand is not None, "origins_for was just checked"
         sched._place(self.rec, cand, t, start_delay=t_mig + extra_delay)
@@ -1091,8 +1090,7 @@ class Preempt(Action):
         sim = pod.sim.remove(victim.job.job_id)
         victim.suspended = SuspendSnapshot(
             work_done=sim.work_done, work_total=sim.work_total,
-            fixed_remaining=sim.fixed_s, pinned=sim.pinned,
-            step_time=sim.step_time, bytes=cost.bytes,
+            duration_s=sim.fixed_s, bytes=cost.bytes,
             delay_remaining=sim.delay_s)
         victim.preemptions += 1
         victim.suspend_s = t
@@ -1292,18 +1290,10 @@ class MigrateAcrossPods(Action):
         # re-admit on the destination with progress intact; the relocation
         # pipeline (save + restore over the DCN) and any unburned earlier
         # migration debt delay its restart
-        admit_kw = {}
-        duration = None
-        if sim.pinned:
-            duration = sim.fixed_s          # wall-clock contract
-        elif sim.fixed_s is not None:
-            admit_kw["fixed_remaining"] = sim.fixed_s
-        else:
-            admit_kw["work_done"] = sim.work_done
         finish = dest.sim.admit(
             victim.job.job_id, sim.n_chips, sim.u_compute, sim.step_time,
-            sim.steps, t, duration_s=duration,
-            start_delay=cost.total_s + sim.delay_s, **admit_kw)
+            sim.steps, t, duration_s=sim.fixed_s,   # pinned: wall contract
+            start_delay=cost.total_s + sim.delay_s, work_done=sim.work_done)
         alloc = dest.partitioner.allocate(profile, tag=victim.job.tag,
                                           origin=self.dest_origin)
         victim.pod_idx = dest.idx
@@ -1318,8 +1308,7 @@ class MigrateAcrossPods(Action):
         dest.slice_jobs[alloc.slice_id] = victim
         victim.version += 1
         sched._push(finish, "finish", (victim, victim.version))
-        if not sched.frozen_durations:
-            sched._resync(dest, t)   # the newcomer slows dest co-tenants
+        sched._resync(dest, t)   # the newcomer slows dest co-tenants
         return cost
 
 
@@ -1575,7 +1564,6 @@ class Grow(Action):
         pod.sim.resize(rec.job.job_id, sc.profile.n_chips,
                        rec.u_compute, sc.step_time)
         sched._charge_migration(pod, moved_bytes, [rec], t)
-        sched._reissue_after_resize(pod, rec, t)
 
 
 # the find() scanners the policies enumerate, in deterministic kind order

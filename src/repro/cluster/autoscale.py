@@ -149,7 +149,6 @@ class ShrinkTenant(Action):
         pod.sim.resize(rec.job.job_id, small.profile.n_chips,
                        rec.u_compute, small.step_time)
         sched._charge_migration(pod, moved_bytes, [rec], t)
-        sched._reissue_after_resize(pod, rec, t)
 
 
 class MigrateTenant(MigrateAcrossPods):

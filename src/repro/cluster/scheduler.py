@@ -46,13 +46,11 @@ job), ``"grow"`` (extend a running job into freed neighbour chips), and
 ``"migrate"`` (relocate a lower-priority job to another pod over the DCN
 — see ``MigrateAcrossPods``). The legacy ``elastic``/``priorities``/
 ``grow`` boolean kwargs are deprecation shims onto that allowlist and
-reproduce the PR 2/3/4 behaviour bit-for-bit.
+make the same decisions as the equivalent ``PolicySpec``.
 
-``frozen_durations=True`` is the compatibility mode: durations are fixed
-at admission time with the legacy float arithmetic and never re-solved,
-reproducing the PR 2 scheduler's numbers bit-for-bit. Crafted jobs with
-pinned ``duration_s`` skip throttle modeling in both modes so tests stay
-exactly deterministic.
+Crafted jobs with a pinned ``duration_s`` skip throttle modeling: their
+wall time is a contract, so tests built from them stay exactly
+deterministic.
 
 Units, everywhere in this module: virtual time and durations in seconds
 (nominal = unthrottled work seconds; wall = after throttle stretch and
@@ -94,18 +92,14 @@ class SuspendSnapshot:
     """Progress frozen at checkpoint-eviction time, restored at resume.
 
     ``work_done``/``work_total`` are nominal (unthrottled) seconds for
-    progress jobs; ``fixed_remaining`` is remaining wall seconds for
-    pinned/frozen jobs (``pinned`` tells which); ``step_time`` is the
-    evicted slice's nominal seconds per step (re-bases a frozen remainder
-    onto a different resume profile); ``bytes`` is the checkpoint volume
-    written at save time — the restore pays the same bytes back;
-    ``delay_remaining`` is unburned wall delay (seconds) from an earlier
-    charged migration, still owed after the resume."""
+    progress jobs; ``duration_s`` is the remaining wall seconds of a
+    pinned job (``Job.duration_s``) and None for a progress job; ``bytes``
+    is the checkpoint volume written at save time — the restore pays the
+    same bytes back; ``delay_remaining`` is unburned wall delay (seconds)
+    from an earlier charged migration, still owed after the resume."""
     work_done: float
     work_total: float
-    fixed_remaining: Optional[float]
-    pinned: bool
-    step_time: float
+    duration_s: Optional[float]
     bytes: int
     delay_remaining: float = 0.0
 
@@ -211,8 +205,8 @@ class EventHeap:
     Purging must not change *when* the event loop advances virtual time:
     the progress/energy accruals are piecewise float sums whose grouping
     is set by pop times, so dropping a stale pop point regroups the
-    summation and drifts the pinned goldens by ulps (measured: the
-    progress-mode trace0 timeline sha). Compaction therefore keeps each
+    summation and drifts the pinned golden times by ulps (measured on
+    the trace-0 timeline). Compaction therefore keeps each
     purged entry's bare *time* in a side heap of floats (one boxed
     double per entry vs a ~150+-byte tuple chain whose payload pins
     records and versions alive) and replays it as a
@@ -285,7 +279,6 @@ class ClusterScheduler:
                  pod: PodSpec = V5E_POD, *,
                  min_throttle: float = 0.8,
                  horizon_s: Optional[float] = None,
-                 frozen_durations: bool = False,
                  spec: Optional[PolicySpec] = None,
                  elastic: Optional[bool] = None,
                  priorities: Optional[bool] = None,
@@ -316,7 +309,6 @@ class ClusterScheduler:
         self.policy = get_policy(policy) if isinstance(policy, str) else policy
         self.min_throttle = min_throttle
         self.horizon_s = horizon_s
-        self.frozen_durations = frozen_durations
         flag_spec = deprecated_flags_spec(elastic, priorities, grow)
         if flag_spec is not None and spec is not None:
             raise ValueError("pass either spec= or the deprecated "
@@ -339,8 +331,7 @@ class ClusterScheduler:
         self.serving_slots = serving_slots
         self.serving_max_seq = serving_max_seq
         self.serving_max_new = serving_max_new
-        self.pods = [PodState(i, StaticPartitioner(pod),
-                              PodSimulator(pod, frozen=frozen_durations),
+        self.pods = [PodState(i, StaticPartitioner(pod), PodSimulator(pod),
                               mode=self.base_mode)
                      for i in range(n_pods)]
         base_ladder = ladder_for(self._modes[self.base_mode])
@@ -623,14 +614,14 @@ class ClusterScheduler:
         return cands
 
     def _is_fixed(self, rec: JobRecord) -> bool:
-        """Fixed-duration jobs (pinned or frozen mode) are event-driven and
-        never re-projected; only explicit delays move their finish."""
-        return self.frozen_durations or rec.job.duration_s is not None
+        """Pinned-duration jobs are event-driven and never re-projected;
+        only explicit delays move their finish."""
+        return rec.job.duration_s is not None
 
     def _resync(self, pod: PodState, t: float) -> None:
         """Re-project every progress job on the pod after a mix change and
         re-issue the finish events that moved (stale versions are skipped
-        by the event loop). No-op in frozen mode."""
+        by the event loop)."""
         txn_touch(self, pod)
         for jid, fin in pod.sim.finish_times(t).items():
             rec = pod.jobs.get(jid)
@@ -737,7 +728,7 @@ class ClusterScheduler:
         job = rec.job
         u = self._u_for(rec, cand.terms)
         duration = job.duration_s
-        admit_kw = {}
+        work_done = 0.0
         if rec.suspended is not None:
             snap = rec.suspended
             restore_s = self.perf.checkpoint_cost(
@@ -752,23 +743,17 @@ class ClusterScheduler:
             rec.resume_s = t
             rec.checkpoint_bytes += snap.bytes
             rec.checkpoint_delay_s += restore_s
-            if snap.fixed_remaining is not None and snap.pinned:
-                duration = snap.fixed_remaining   # wall-clock contract
-            elif snap.fixed_remaining is not None:
-                # frozen remainder re-based onto the resume profile
-                admit_kw["fixed_remaining"] = (
-                    snap.fixed_remaining
-                    * cand.terms.step_time / snap.step_time)
+            if snap.duration_s is not None:
+                duration = snap.duration_s   # wall-clock contract
             else:
                 frac = (snap.work_done / snap.work_total
                         if snap.work_total else 0.0)
-                admit_kw["work_done"] = frac * (job.steps
-                                                * cand.terms.step_time)
+                work_done = frac * (job.steps * cand.terms.step_time)
             rec.suspended = None
         finish = pod.sim.admit(
             job.job_id, cand.profile.n_chips, u, cand.terms.step_time,
             job.steps, t, duration_s=duration, start_delay=start_delay,
-            **admit_kw)
+            work_done=work_done)
         rec.pod_idx = pod.idx
         rec.profile_name = cand.profile.name
         rec.rung = cand.rung or cand.profile.name
@@ -792,8 +777,7 @@ class ClusterScheduler:
         pod.slice_jobs[rec.slice_id] = rec
         rec.version += 1
         self._push(rec.finish_s, FINISH, (rec, rec.version))
-        if not self.frozen_durations:
-            self._resync(pod, t)   # the new tenant slows every co-tenant
+        self._resync(pod, t)   # the new tenant slows every co-tenant
 
     def _complete(self, rec: JobRecord, t: float) -> None:
         pod = self.pods[rec.pod_idx]
@@ -806,8 +790,7 @@ class ClusterScheduler:
             pod.runtime.remove_tenant(rec.job.tag)
         else:
             pod.partitioner.release(rec.slice_id)
-        if not self.frozen_durations:
-            self._resync(pod, t)   # survivors speed back up
+        self._resync(pod, t)   # survivors speed back up
 
     # ------------------------------------------------------------------
     # shared pricing mechanics the actions call
@@ -840,22 +823,8 @@ class ClusterScheduler:
                     r.finish_s += t_mig
                     r.version += 1
                     self._push(r.finish_s, FINISH, (r, r.version))
-            if not self.frozen_durations:
-                self._resync(pod, t)
+            self._resync(pod, t)
         return t_mig
-
-    def _reissue_after_resize(self, pod: PodState, rec: JobRecord,
-                              t: float) -> None:
-        """Frozen durations never self-re-project, but a resize re-bases
-        the remaining frozen wall time — re-issue the finish event."""
-        if not (self.frozen_durations and rec.job.duration_s is None):
-            return
-        txn_touch(self, pod)
-        fin = pod.sim.projected_finish(rec.job.job_id, t)
-        if fin != rec.finish_s:
-            rec.finish_s = fin
-            rec.version += 1
-            self._push(fin, FINISH, (rec, rec.version))
 
     # ------------------------------------------------------------------
     # elastic grow sweep (the Grow action, after completions and — under

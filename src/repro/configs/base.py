@@ -79,7 +79,6 @@ class ModelConfig:
     # boundaries are S-sharded over "model" (divides saved activations by the
     # model-axis size at the cost of per-layer gather/scatter collectives).
     seq_shard_residuals: bool = False
-    attn_impl: str = "xla"  # "xla" (scan flash) | "pallas" (TPU kernel)
     attn_chunk: int = 1024  # KV-block size for the scan flash attention
 
     def __post_init__(self):

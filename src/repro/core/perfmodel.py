@@ -23,9 +23,7 @@ Two layers:
 * ``PodSimulator`` — a progress-based execution engine. Jobs carry
   ``work_done / work_total``; every admission, completion, resize, or delay
   re-solves the pod throttle for the new mix and re-projects every remaining
-  finish time. ``frozen=True`` reproduces the legacy fixed-at-admission
-  durations bit-for-bit (same float expressions, same summation order), so
-  the PR 2 scheduler numbers stay exactly reproducible.
+  finish time.
 """
 from __future__ import annotations
 
@@ -483,8 +481,8 @@ def model_for_mode(chip: ChipSpec, mode, twin: Optional[TwinSpec] = None
 class SimJob:
     """Progress state of one instance on the simulated pod.
 
-    ``fixed_s`` set → the duration is pinned (crafted job) or frozen at
-    admission (compatibility mode): wall time only, never re-solved.
+    ``fixed_s`` set → the duration is pinned (``Job.duration_s``): wall
+    time only, never re-solved.
     Otherwise ``work_done/work_total`` are in *nominal unthrottled seconds*;
     the wall-time cost of one nominal second under throttle f is
     ``stretch(f) = u/f + (1 - u)`` (only the compute share scales)."""
@@ -496,8 +494,7 @@ class SimJob:
     work_total: float = 0.0
     work_done: float = 0.0
     delay_s: float = 0.0        # pending wall delay (migration) before work
-    fixed_s: Optional[float] = None   # remaining pinned/frozen wall duration
-    pinned: bool = False        # fixed_s came from Job.duration_s, not frozen
+    fixed_s: Optional[float] = None   # remaining pinned wall duration
 
     @property
     def progress(self) -> float:
@@ -516,14 +513,10 @@ class PodSimulator:
     The owner (``cluster.ClusterScheduler``) drives virtual time through
     ``advance`` between its events; every mutation (``admit`` / ``remove`` /
     ``resize`` / ``delay``) changes the mix, after which ``finish_times``
-    re-solves the throttle and re-projects every live progress job. In
-    ``frozen=True`` mode durations are fixed at admission with the exact
-    legacy float expressions and ``finish_times`` projects nothing — the
-    event stream is bit-identical to the PR 2 scheduler."""
+    re-solves the throttle and re-projects every live progress job."""
 
-    def __init__(self, pod: PodSpec = V5E_POD, frozen: bool = False):
+    def __init__(self, pod: PodSpec = V5E_POD):
         self.pod = pod
-        self.frozen = frozen
         self.now = 0.0
         self.jobs: Dict[int, SimJob] = {}
         self._gen = 0          # bumped on every mix mutation
@@ -608,38 +601,22 @@ class PodSimulator:
               step_time: float, steps: int, t: float, *,
               duration_s: Optional[float] = None,
               start_delay: float = 0.0,
-              work_done: float = 0.0,
-              fixed_remaining: Optional[float] = None) -> float:
+              work_done: float = 0.0) -> float:
         """Add an instance at time ``t``; returns its projected finish.
 
         Pinned ``duration_s`` → wall-clock duration regardless of throttle
-        (crafted traces stay exactly deterministic). Frozen mode computes
-        the duration once, with the legacy expression, at the admission-time
-        throttle of the mix *including* the new instance.
+        (crafted traces stay exactly deterministic).
 
-        The resume-from-checkpoint path re-admits a previously evicted
-        instance with its progress preserved: ``work_done`` (nominal
-        unthrottled seconds already completed, progress jobs) or
-        ``fixed_remaining`` (remaining wall seconds, frozen-mode jobs —
-        overrides the legacy admission-time expression). A resumed pinned
-        job simply passes its remaining wall time as ``duration_s``."""
+        The resume-from-checkpoint and migration paths re-admit an evicted
+        instance with its progress preserved: a progress job passes
+        ``work_done`` (nominal unthrottled seconds already completed), a
+        pinned job its remaining wall time as ``duration_s``."""
         assert key not in self.jobs
         job = SimJob(key=key, n_chips=n_chips, u_compute=u_compute,
                      step_time=step_time, steps=steps, delay_s=start_delay)
         if duration_s is not None:
             job.fixed_s = duration_s
-            job.pinned = True
             finish = t + start_delay + duration_s
-        elif fixed_remaining is not None:
-            job.fixed_s = fixed_remaining
-            finish = t + start_delay + fixed_remaining
-        elif self.frozen:
-            # legacy float arithmetic, term for term (bit-identity contract)
-            f = throttle_factor(self.loads(job.load()), self.pod)
-            t_comp = step_time * u_compute
-            dur = steps * (t_comp / f + (step_time - t_comp))
-            job.fixed_s = dur
-            finish = t + start_delay + dur
         else:
             job.work_total = steps * step_time
             job.work_done = min(work_done, job.work_total)
@@ -662,15 +639,10 @@ class PodSimulator:
                step_time: float) -> None:
         """Elastic shrink/grow: move an instance to a different profile,
         preserving its *fraction* of work done — remaining work is re-based
-        onto the new step time. Pinned wall-clock durations stay pinned;
-        a frozen (fixed-at-admission) duration has its remaining wall time
-        scaled by the step-time ratio."""
+        onto the new step time. Pinned wall-clock durations stay pinned:
+        ``Job.duration_s`` is a profile-free contract."""
         j = self.jobs[key]
-        if j.pinned:
-            pass   # Job.duration_s is a wall-clock contract, profile-free
-        elif j.fixed_s is not None:
-            j.fixed_s *= step_time / j.step_time
-        else:
+        if j.fixed_s is None:
             frac = j.progress
             j.work_total = j.steps * step_time
             j.work_done = frac * j.work_total
@@ -680,17 +652,10 @@ class PodSimulator:
         self._gen += 1
 
     # -- projection -----------------------------------------------------
-    def projected_finish(self, key: int, t: float) -> float:
-        """Projected finish of one instance (fixed or progress) at ``t``."""
-        j = self.jobs[key]
-        if j.fixed_s is not None:
-            return t + j.delay_s + j.fixed_s
-        return t + j.delay_s + (j.work_total - j.work_done) \
-            * j.stretch(self.throttle())
     def finish_times(self, t: float) -> Dict[int, float]:
         """Projected finish for every *progress* job under the current mix
-        (fixed-duration jobs are event-driven by the owner and never
-        re-projected — that is the frozen/pinned contract)."""
+        (pinned jobs are event-driven by the owner and never re-projected
+        — that is the pinned contract)."""
         live = [j for j in self.jobs.values() if j.fixed_s is None]
         if not live:
             return {}

@@ -3,10 +3,7 @@
 * ``causal_flash_attention`` — differentiable causal self-attention in the
   model's (B, S, H, hd) layout (custom VJP over the forward-with-stats and
   the two backward kernels); ``models.attention.attention_core`` sends
-  causal self-attention on a TPU here.
-* ``flash_attention`` — forward only, (B, S, H, hd) (``attn_impl="pallas"``).
-* ``flash_attention_grads`` — out and (dq, dk, dv) in the folded (BH, S, hd)
-  layout, for tests.
+  causal self-attention on one TPU device here.
 * ``moe_route``, ``moe_dispatch``, ``moe_combine`` — the MoE layer's row
   permute: the dispatch into expert order (XLA's gather) and the weighted
   combine back (``kernels/moe_permute.py``), custom VJPs whose backward
@@ -95,33 +92,6 @@ def _causal_bwd(scale, res, dout):
 
 
 causal_flash_attention.defvjp(_causal_fwd, _causal_bwd)
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
-                                             "block_k"))
-def flash_attention(q, k, v, *, causal: bool = True, scale=None,
-                    block_q=None, block_k=None):
-    """q, k, v: (B, S, H, hd) — heads are folded/unfolded here."""
-    B, S, H, hd = q.shape
-    out = _fa.flash_attention_fwd(
-        _fold(q), _fold(k), _fold(v), causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=_interpret())
-    return _unfold(out, B, H)
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
-def flash_attention_grads(q, k, v, dout, *, causal: bool = True,
-                          block_q=None, block_k=None):
-    """Full flash backward via the Pallas kernels.
-    q, k, v, dout: (BH, S, hd). Returns (out, dq, dk, dv)."""
-    interp = _interpret()
-    out, lse = _fa.flash_attention_fwd_stats(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interp)
-    dq, dk, dv = _fa.flash_attention_bwd(
-        q, k, v, out, lse, dout, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interp)
-    return out, dq, dk, dv
 
 
 @functools.partial(jax.tree_util.register_dataclass,

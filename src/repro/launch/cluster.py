@@ -238,9 +238,6 @@ def main() -> None:
                          "rung — run with and without --twin to flip the "
                          "SLO verdict")
     add_policy_args(ap)
-    ap.add_argument("--frozen-durations", action="store_true",
-                    help="legacy mode: freeze durations at admission-time "
-                         "throttle instead of re-solving on mix changes")
     args = ap.parse_args()
 
     if args.modes:
@@ -334,8 +331,7 @@ def main() -> None:
     sched = ClusterScheduler(
         n_pods=args.pods, policy=args.placement,
         pod=PodSpec(chip=get_chip(args.chip)),
-        min_throttle=args.min_throttle, horizon_s=args.horizon,
-        frozen_durations=args.frozen_durations, spec=spec,
+        min_throttle=args.min_throttle, horizon_s=args.horizon, spec=spec,
         execute_serving=not args.no_execute, autoscaler=autoscaler,
         twin=args.twin, mode=args.mode)
     records, metrics = sched.run(jobs)

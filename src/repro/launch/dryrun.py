@@ -264,7 +264,7 @@ def main() -> None:
     ap.add_argument("--include-paper-archs", action="store_true")
     ap.add_argument("--remat", default=None)
     ap.add_argument("--set", action="append", default=[],
-                    help="config override key=value (e.g. attn_impl=xla_cv)")
+                    help="config override key=value (e.g. attn_chunk=512)")
     ap.add_argument("--tag", default="", help="artifact filename suffix")
     ap.add_argument("--out", default=ART_DIR)
     args = ap.parse_args()

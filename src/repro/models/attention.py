@@ -12,18 +12,15 @@ softmax), GQA, RoPE/M-RoPE, decode.
   * decode (Sq == 1, not causal) — ``decode_attention``, one pass over the
     cache;
   * everything else, and every call off the TPU (the CPU tests, the
-    dry-run) — ``attn_impl="xla"``: a lax.scan over KV chunks with online
-    softmax. Peak memory is O(Sq * chunk) instead of O(Sq * Sk), which is
-    what makes the 32k-prefill cells compile with sane footprints.
-    ``attn_impl="pallas"`` (forward-only kernel) and ``"xla_cv"`` (XLA-level
-    custom VJP) remain as options there.
+    dry-run) — ``flash_attention``: a lax.scan over ``cfg.attn_chunk``-wide
+    KV chunks with online softmax. Peak memory is O(Sq * chunk) instead of
+    O(Sq * Sk), which is what keeps 32k-token prefill within its memory.
 
 GQA is handled by gather-expanding K/V head-wise (a local gather — verified to
 introduce zero collectives when Q-heads are model-sharded and KV replicated).
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -216,115 +213,6 @@ def decode_attention(q, k, v, *, kv_len=None, scale: Optional[float] = None):
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-# ---------------------------------------------------------------------------
-# flash attention with custom VJP (hillclimb: §Perf iteration 1)
-#
-# The plain scan implementation lets jax's reverse-mode save per-chunk score
-# residuals — a (chunks, B, H, Sq, chunk) stack per layer that the dry-run
-# shows as the dominant HBM-traffic site in training (read-modify-write
-# convert fusions ×layers×microbatches). The custom VJP saves only the
-# (B, H, Sq) logsumexp stats and recomputes p per chunk in the backward —
-# the textbook flash-attention backward, here at the XLA level.
-# ---------------------------------------------------------------------------
-def _flash_fwd_stats(q, k, v, *, causal, chunk, scale):
-    """Like flash_attention but also returns lse = m + log(l)."""
-    B, Sq, H, hd = q.shape
-    Sk = k.shape[1]
-    chunk = min(chunk, Sk)
-    assert Sk % chunk == 0, (Sk, chunk)
-    n_chunks = Sk // chunk
-    qf = (q.astype(jnp.float32) * scale).transpose(0, 2, 1, 3)
-    kc = jnp.moveaxis(k.transpose(0, 2, 1, 3).reshape(B, H, n_chunks, chunk, hd), 2, 0)
-    vc = jnp.moveaxis(v.transpose(0, 2, 1, 3).reshape(B, H, n_chunks, chunk, hd), 2, 0)
-    pos_q = jnp.arange(Sq)
-
-    def body(carry, inputs):
-        m, l, acc = carry
-        j, kj, vj = inputs
-        s = jax.lax.dot_general(qf, kj, (((3,), (3,)), ((0, 1), (0, 1))),
-                                preferred_element_type=jnp.float32)
-        if causal:
-            pos_k = j * chunk + jnp.arange(chunk)
-            s = jnp.where((pos_q[:, None] >= pos_k[None, :])[None, None],
-                          s, -jnp.inf)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe[..., None])
-        if causal:
-            p = jnp.where((pos_q[:, None] >= pos_k[None, :])[None, None], p, 0.0)
-        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        acc_new = acc * corr[..., None] + jax.lax.dot_general(
-            p.astype(vj.dtype), vj, (((3,), (2,)), ((0, 1), (0, 1))),
-            preferred_element_type=jnp.float32)
-        return (m_new, l_new, acc_new), None
-
-    m0 = jnp.full((B, H, Sq), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((B, H, Sq), jnp.float32)
-    a0 = jnp.zeros((B, H, Sq, hd), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0),
-                                  (jnp.arange(n_chunks), kc, vc))
-    out = (acc / jnp.maximum(l, 1e-30)[..., None]).transpose(0, 2, 1, 3)
-    lse = jnp.where(jnp.isfinite(m), m, 0.0) + jnp.log(jnp.maximum(l, 1e-30))
-    return out.astype(q.dtype), lse  # lse: (B, H, Sq)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention_cv(q, k, v, causal: bool, chunk: int, scale: float):
-    out, _ = _flash_fwd_stats(q, k, v, causal=causal, chunk=chunk, scale=scale)
-    return out
-
-
-def _flash_cv_fwd(q, k, v, causal, chunk, scale):
-    out, lse = _flash_fwd_stats(q, k, v, causal=causal, chunk=chunk, scale=scale)
-    return out, (q, k, v, out, lse)
-
-
-def _flash_cv_bwd(causal, chunk, scale, res, dout):
-    q, k, v, out, lse = res
-    B, Sq, H, hd = q.shape
-    Sk = k.shape[1]
-    chunk = min(chunk, Sk)
-    n_chunks = Sk // chunk
-    qf = (q.astype(jnp.float32) * scale).transpose(0, 2, 1, 3)   # (B,H,Sq,hd)
-    do = dout.astype(jnp.float32).transpose(0, 2, 1, 3)
-    of = out.astype(jnp.float32).transpose(0, 2, 1, 3)
-    D = jnp.sum(do * of, axis=-1)                                # (B,H,Sq)
-    kc = jnp.moveaxis(k.transpose(0, 2, 1, 3).reshape(B, H, n_chunks, chunk, hd), 2, 0)
-    vc = jnp.moveaxis(v.transpose(0, 2, 1, 3).reshape(B, H, n_chunks, chunk, hd), 2, 0)
-    pos_q = jnp.arange(Sq)
-
-    def body(dq_acc, inputs):
-        j, kj, vj = inputs
-        s = jax.lax.dot_general(qf, kj, (((3,), (3,)), ((0, 1), (0, 1))),
-                                preferred_element_type=jnp.float32)
-        p = jnp.exp(s - lse[..., None])                          # (B,H,Sq,ck)
-        if causal:
-            pos_k = j * chunk + jnp.arange(chunk)
-            p = jnp.where((pos_q[:, None] >= pos_k[None, :])[None, None], p, 0.0)
-        dp = jax.lax.dot_general(do, vj, (((3,), (3,)), ((0, 1), (0, 1))),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - D[..., None])                             # (B,H,Sq,ck)
-        dq_acc = dq_acc + jax.lax.dot_general(
-            ds, kj, (((3,), (2,)), ((0, 1), (0, 1))),
-            preferred_element_type=jnp.float32)
-        dk_j = jax.lax.dot_general(ds, qf, (((2,), (2,)), ((0, 1), (0, 1))),
-                                   preferred_element_type=jnp.float32)
-        dv_j = jax.lax.dot_general(p, do, (((2,), (2,)), ((0, 1), (0, 1))),
-                                   preferred_element_type=jnp.float32)
-        return dq_acc, (dk_j, dv_j)
-
-    dq0 = jnp.zeros((B, H, Sq, hd), jnp.float32)
-    dq, (dks, dvs) = jax.lax.scan(body, dq0, (jnp.arange(n_chunks), kc, vc))
-    dq = (dq * scale).transpose(0, 2, 1, 3).astype(q.dtype)
-    dk = jnp.moveaxis(dks, 0, 2).reshape(B, H, Sk, hd).transpose(0, 2, 1, 3)
-    dv = jnp.moveaxis(dvs, 0, 2).reshape(B, H, Sk, hd).transpose(0, 2, 1, 3)
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
-
-
-flash_attention_cv.defvjp(_flash_cv_fwd, _flash_cv_bwd)
-
-
 def _on_one_tpu() -> bool:
     """The computation runs on one TPU device: the TPU backend, and no
     context mesh of several devices (a Pallas kernel is not partitioned)."""
@@ -352,9 +240,10 @@ def _fused(q, k, *, causal: bool, q_offset, kv_len) -> bool:
 
 def attention_core(cfg: ModelConfig, q, k, v, *, causal: bool, q_offset=0,
                    kv_len=None):
-    """Dispatch on the call's shape and backend, then on ``cfg.attn_impl``;
-    expands GQA heads first. Scores are scaled by ``cfg.attention_multiplier``,
-    or by ``head_dim ** -0.5`` where it is None."""
+    """Dispatch on the call's shape and backend: decode, the fused kernels,
+    or the XLA scan; expands GQA heads first. Scores are scaled by
+    ``cfg.attention_multiplier``, or by ``head_dim ** -0.5`` where it is
+    None."""
     scale = cfg.attention_multiplier
     k = expand_kv(k, cfg.num_heads)
     v = expand_kv(v, cfg.num_heads)
@@ -363,13 +252,6 @@ def attention_core(cfg: ModelConfig, q, k, v, *, causal: bool, q_offset=0,
     if _fused(q, k, causal=causal, q_offset=q_offset, kv_len=kv_len):
         from repro.kernels import ops as kops
         return kops.causal_flash_attention(q, k, v, scale)
-    if cfg.attn_impl == "pallas" and causal and q.shape[1] == k.shape[1]:
-        from repro.kernels import ops as kops
-        return kops.flash_attention(q, k, v, causal=True, scale=scale)
-    if (cfg.attn_impl == "xla_cv" and causal and kv_len is None
-            and k.shape[1] % min(cfg.attn_chunk, k.shape[1]) == 0):
-        return flash_attention_cv(q, k, v, True, cfg.attn_chunk,
-                                  scale or cfg.head_dim ** -0.5)
     return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
                            kv_len=kv_len, chunk=cfg.attn_chunk, scale=scale)
 

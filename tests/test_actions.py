@@ -50,8 +50,7 @@ def fingerprint(sched):
             "dead": (part._grid == -2).tobytes(),
             "sim_now": pod.sim.now,
             "sim": {k: (j.n_chips, j.u_compute, j.step_time, j.steps,
-                        j.work_total, j.work_done, j.delay_s, j.fixed_s,
-                        j.pinned)
+                        j.work_total, j.work_done, j.delay_s, j.fixed_s)
                     for k, j in pod.sim.jobs.items()},
             "draw": pod.sim.draw(),
             "throttle": pod.sim.throttle(),

@@ -1,11 +1,10 @@
 """ClusterScheduler stack: trace determinism, MISO-style placement,
 fragmentation stranding + repack recovery (the bench_cluster scenario),
 modeled migration cost, power-cap admission, the progress-based engine
-(retro-active stretching, frozen-mode bit-identity with the PR 2
-scheduler, elastic SLO rescue), the Action API (PolicySpec allowlist,
+(retro-active stretching, the seeded trace-0 golden, elastic SLO
+rescue), the Action API (PolicySpec allowlist,
 deprecation shims, cross-pod migration over the DCN, look-ahead
 chaining), live SliceRuntime execution, and metrics sanity."""
-import hashlib
 import subprocess
 import sys
 from collections import Counter
@@ -24,6 +23,7 @@ from repro.cluster.placement import (FirstFitPolicy, FragAwarePolicy,
 from repro.cluster.trace import (BATCH, KIND_PRIORITY, KINDS, SERVING,
                                  TRAINING, Job)
 from repro.core.hw import V5E_POD
+from test_timeline_pins import assert_pinned, trace0
 
 
 # ---------------------------------------------------------------------------
@@ -192,36 +192,62 @@ def test_scheduler_single_use():
 # ---------------------------------------------------------------------------
 # progress-based engine (PerfModel / PodSimulator rewrite)
 # ---------------------------------------------------------------------------
-# Golden numbers recorded from the PR 2 scheduler (fixed-at-admission
-# durations) on this exact seeded trace, before the PodSimulator rewrite.
-# ``frozen_durations=True`` must reproduce them bit-for-bit.
-_PR2_TRACE = dict(seed=0, n_jobs=48, mean_interarrival_s=5.0)
-_PR2_GOLDEN = {
-    "makespan_s": 5841.312618401943,
-    "energy_J": 164866198.0380577,
-    "mean_queue_delay_s": 149.83535556820502,
-    "p95_queue_delay_s": 352.84254173889997,
-    "slo_attainment": 0.16666666666666666,
-    "chip_hour_utilization": 0.38907819980013525,
-    "frag_time_avg": 0.29202000328138994,
+# The progress engine's golden on the seeded 48-job trace: every scalar
+# metric of the frag_repack run, compared within 1e-12 relative (a
+# regrouped float sum moves the last ulp; a changed decision moves far
+# more), plus its timeline pin (decisions exact, times within 1e-9).
+_TRACE0_GOLDEN = {
+    "n_jobs": 48,
+    "placed": 48,
+    "completed": 48,
+    "left_queued": 0,
+    "still_running": 0,
+    "makespan_s": 5890.25934641167,
+    "mean_queue_delay_s": 157.75783280464077,
+    "p95_queue_delay_s": 364.18618643706225,
+    "slo_attainment": 0.1875,
+    "chip_hour_utilization": 0.3702098211438245,
+    "frag_time_avg": 0.2792904051365157,
+    "energy_J": 168843007.22412205,
+    "energy_per_chip_hour_kJ": 1047.222235013925,
     "repacks": 1,
+    "repack_failures": 0,
+    "shrinks": 0,
+    "grows": 0,
+    "preemptions": 0,
+    "resumes": 0,
+    "wasted_checkpoint_chip_s": 0.0,
+    "migrated_bytes": 3848290697216,
+    "migration_s": 3.758096384,
+    "migrations": 0,
+    "dcn_migrated_bytes": 0,
+    "dcn_migration_s": 0.0,
     "power_deferrals": 0,
-    "migrated_bytes": 3573412790272,
-    "migration_s": 3.489660928,
+    "reconfigs": 0,
+    "rescue_probes_priced": 0,
+    "probe_cache_hits": 0,
+    "serving_p50_s": 0.0,
+    "serving_p99_s": 0.0,
+    "serving_slo_hit_rate": 0.0,
+    "serving_chip_hours": 0.0,
+    "chip_hours_per_slo_hit": 0.0,
+    "autoscale_resizes": 0,
 }
-_PR2_TIMELINE_SHA = \
-    "429696d0b32a6c03aec769b791fd0683498c4ec9749b15f463820d6b919fb9c8"
 
 
-def test_frozen_durations_bit_identical_to_pr2_scheduler():
-    trace = generate_trace(TraceConfig(**_PR2_TRACE))
-    records, m = ClusterScheduler(n_pods=1, policy="frag_repack",
-                                  frozen_durations=True).run(trace)
-    for key, want in _PR2_GOLDEN.items():
-        assert getattr(m, key) == want, key   # exact, not approx
-    timeline = repr([(r.job.job_id, r.place_s, r.finish_s) for r in records])
-    assert (hashlib.sha256(timeline.encode()).hexdigest()
-            == _PR2_TIMELINE_SHA)
+def _assert_trace0_golden(records, m):
+    got = m.as_dict()
+    assert got.keys() - {"policy"} == _TRACE0_GOLDEN.keys()
+    for key, want in _TRACE0_GOLDEN.items():
+        assert got[key] == pytest.approx(want, rel=1e-12, abs=0.0), key
+    assert_pinned("trace0-frag_repack", records, m)
+
+
+def test_trace0_golden_progress_engine():
+    records, m = ClusterScheduler(n_pods=1, policy="frag_repack").run(
+        trace0())
+    assert m.makespan_s == 5890.25934641167
+    _assert_trace0_golden(records, m)
 
 
 def _stretch_jobs():
@@ -233,35 +259,41 @@ def _stretch_jobs():
 
 
 def test_later_arrival_retroactively_stretches_in_flight_job():
-    frozen_rec, _ = ClusterScheduler(
-        n_pods=1, policy="frag", min_throttle=0.0,
-        frozen_durations=True).run(_stretch_jobs())
-    progress_rec, _ = ClusterScheduler(
+    alone_rec, _ = ClusterScheduler(
+        n_pods=1, policy="frag", min_throttle=0.0).run(_stretch_jobs()[:1])
+    both_rec, _ = ClusterScheduler(
         n_pods=1, policy="frag", min_throttle=0.0).run(_stretch_jobs())
-    f_a = next(r for r in frozen_rec if r.job.job_id == 0)
-    p_a = next(r for r in progress_rec if r.job.job_id == 0)
-    # frozen: job 0's duration was fixed when it ran alone (throttle 1.0);
-    # progress: job 1's arrival re-solves the mix and stretches job 0
-    assert p_a.finish_s > f_a.finish_s
+    alone = alone_rec[0]
+    p_a = next(r for r in both_rec if r.job.job_id == 0)
+    # alone, job 0 runs unthrottled; job 1's arrival re-solves the mix
+    # and stretches job 0
+    assert p_a.finish_s > alone.finish_s
     # the stretch is retro-active within the run: the projection at
     # placement time (duration_s) is exceeded by the actual finish
     assert p_a.finish_s > p_a.place_s + p_a.duration_s
-    # and job 1 finishes *earlier* than frozen mode predicts: once job 0
-    # completes, the survivor speeds back up (frozen can't model that)
-    f_b = next(r for r in frozen_rec if r.job.job_id == 1)
-    p_b = next(r for r in progress_rec if r.job.job_id == 1)
-    assert p_b.finish_s < f_b.finish_s
+    # and job 1 finishes *earlier* than projected at its admission: once
+    # job 0 completes, the survivor speeds back up
+    p_b = next(r for r in both_rec if r.job.job_id == 1)
+    assert p_b.finish_s < p_b.place_s + p_b.duration_s
 
 
 def test_pinned_duration_traces_identical_in_both_modes():
-    # the fragmentation showcase pins every duration, so the progress
-    # engine must reproduce the frozen timeline exactly
-    a = ClusterScheduler(n_pods=1, policy="frag_repack",
-                         horizon_s=3000.0).run(fragmentation_showcase())[1]
-    b = ClusterScheduler(n_pods=1, policy="frag_repack", horizon_s=3000.0,
-                         frozen_durations=True).run(
-                             fragmentation_showcase())[1]
-    assert a == b
+    # the fragmentation showcase pins every duration: each job finishes
+    # at place + delays + duration_s whatever the throttle does; the only
+    # delay is the repack's host-link migration, paid by the moved jobs
+    # and by the job that waited for the repack
+    records, m = ClusterScheduler(
+        n_pods=1, policy="frag_repack",
+        horizon_s=3000.0).run(fragmentation_showcase())
+    assert m.repacks == 1 and m.migration_s > 0
+    delays = []
+    for r in records:
+        assert r.job.duration_s is not None and r.placed
+        delay = r.finish_s - r.place_s - r.job.duration_s
+        assert (delay == pytest.approx(0.0, abs=1e-9)
+                or delay == pytest.approx(m.migration_s)), (r.job, delay)
+        delays.append(delay > 1e-9)
+    assert any(delays) and not all(delays)
 
 
 # ---------------------------------------------------------------------------
@@ -616,16 +648,17 @@ def test_select_cheapest_comparator():
     assert select_cheapest([i, a]) is a          # infeasible filtered out
 
 
-def test_frozen_priorities_off_reproduces_pr3_golden():
-    # the full golden check lives in
-    # test_frozen_durations_bit_identical_to_pr2_scheduler; this pins the
-    # flag semantics — priorities/grow default OFF and change nothing
-    trace = generate_trace(TraceConfig(**_PR2_TRACE))
-    m_flags = ClusterScheduler(n_pods=1, policy="frag_repack",
-                               frozen_durations=True, priorities=False,
-                               grow=False).run(trace)[1]
-    for key, want in _PR2_GOLDEN.items():
-        assert getattr(m_flags, key) == want, key
+def test_priorities_off_reproduces_trace0_golden():
+    # the full golden check lives in test_trace0_golden_progress_engine;
+    # this pins the flag semantics — priorities/grow default OFF and
+    # change nothing
+    m_default = ClusterScheduler(n_pods=1, policy="frag_repack").run(
+        trace0())[1]
+    records, m_flags = ClusterScheduler(n_pods=1, policy="frag_repack",
+                                        priorities=False,
+                                        grow=False).run(trace0())
+    assert m_flags == m_default
+    _assert_trace0_golden(records, m_flags)
 
 
 # ---------------------------------------------------------------------------
@@ -763,19 +796,13 @@ def test_boolean_shim_equivalent_to_spec_on_showcases():
     assert m_shim == m_spec
 
 
-def test_frozen_golden_identical_under_equivalent_policy_spec():
-    # the PR 2/3/4 golden contract holds for BOTH compat surfaces: the
-    # boolean shims (test_frozen_durations_bit_identical_to_pr2_scheduler
-    # covers defaults) and the explicit empty PolicySpec
-    trace = generate_trace(TraceConfig(**_PR2_TRACE))
+def test_trace0_golden_identical_under_equivalent_policy_spec():
+    # the trace-0 golden holds for BOTH compat surfaces: the defaults
+    # (test_trace0_golden_progress_engine) and the explicit empty
+    # PolicySpec
     records, m = ClusterScheduler(n_pods=1, policy="frag_repack",
-                                  frozen_durations=True,
-                                  spec=PolicySpec()).run(trace)
-    for key, want in _PR2_GOLDEN.items():
-        assert getattr(m, key) == want, key
-    timeline = repr([(r.job.job_id, r.place_s, r.finish_s) for r in records])
-    assert (hashlib.sha256(timeline.encode()).hexdigest()
-            == _PR2_TIMELINE_SHA)
+                                  spec=PolicySpec()).run(trace0())
+    _assert_trace0_golden(records, m)
 
 
 def test_rescue_selection_not_hardcoded_in_scheduler():

@@ -34,10 +34,10 @@ def test_flash_attention_matches_ref(B, S, H, hd, bq, bk, dtype):
     q = jax.random.normal(ks[0], (B, S, H, hd), dtype)
     k = jax.random.normal(ks[1], (B, S, H, hd), dtype)
     v = jax.random.normal(ks[2], (B, S, H, hd), dtype)
-    got = ops.flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
+    got = fa.flash_attention_fwd(fold(q), fold(k), fold(v), causal=True,
+                                 block_q=bq, block_k=bk, interpret=True)
     want = ref.attention_ref(fold(q), fold(k), fold(v), causal=True)
-    want = want.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     assert _rel_err(got, want) < tol
 
@@ -47,10 +47,10 @@ def test_flash_attention_non_causal():
     q = jax.random.normal(ks[0], (2, 128, 2, 64), jnp.float32)
     k = jax.random.normal(ks[1], (2, 128, 2, 64), jnp.float32)
     v = jax.random.normal(ks[2], (2, 128, 2, 64), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=False)
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(4, 128, 64)
+    got = fa.flash_attention_fwd(fold(q), fold(k), fold(v), causal=False,
+                                 interpret=True)
     want = ref.attention_ref(fold(q), fold(k), fold(v), causal=False)
-    want = want.reshape(2, 2, 128, 64).transpose(0, 2, 1, 3)
     assert _rel_err(got, want) < 2e-5
 
 
@@ -98,11 +98,14 @@ def test_flash_backward_kernel_matches_autodiff(causal, bq, bk, n_blocks,
     q, k, v = (jax.random.normal(kk, (B, S, H, hd), dtype) for kk in ks[:3])
     do = jax.random.normal(ks[3], (B, S, H, hd), jnp.float32)
     if n_blocks is None:
-        fold = lambda t: t[:, :, 0]  # noqa: E731  (H = 1)
-        out, dq, dk, dv = ops.flash_attention_grads(
-            fold(q), fold(k), fold(v), fold(do), causal=causal,
-            block_q=bq, block_k=bk)
-        got = [t[:, :, None] for t in (out, dq, dk, dv)]
+        q1, k1, v1, do1 = (t[:, :, 0] for t in (q, k, v, do))  # H = 1
+        out, lse = fa.flash_attention_fwd_stats(
+            q1, k1, v1, causal=causal, block_q=bq, block_k=bk,
+            interpret=True)
+        grads = fa.flash_attention_bwd(
+            q1, k1, v1, out, lse, do1, causal=causal, block_q=bq,
+            block_k=bk, interpret=True)
+        got = [t[:, :, None] for t in (out, *grads)]
     else:
         loss = lambda q, k, v: jnp.sum(  # noqa: E731
             ops.causal_flash_attention(q, k, v).astype(jnp.float32) * do)
@@ -283,8 +286,10 @@ def test_attention_core_off_the_tpu_keeps_the_xla_scan(monkeypatch):
 
 
 def test_flash_custom_vjp_matches_autodiff():
-    """XLA-level flash custom VJP (used by attn_impl=xla_cv) vs autodiff."""
-    from repro.models.attention import flash_attention_cv
+    """The XLA scan's gradient — the attention of every call off the TPU
+    and of every ragged or offset one on it — vs autodiff of naive causal
+    attention."""
+    from repro.models import attention
     ks = jax.random.split(jax.random.PRNGKey(8), 3)
     B, S, H, hd = 2, 256, 2, 64
     q, k, v = (jax.random.normal(kk, (B, S, H, hd), jnp.float32) for kk in ks)
@@ -295,9 +300,10 @@ def test_flash_custom_vjp_matches_autodiff():
         s = jnp.where(mask[None, None], s, -jnp.inf)
         return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
 
-    f_cv = lambda *a: jnp.sum(jnp.sin(flash_attention_cv(*a, True, 64, hd ** -0.5)))
+    f_scan = lambda *a: jnp.sum(jnp.sin(attention.flash_attention(  # noqa: E731
+        *a, causal=True, chunk=64, scale=hd ** -0.5)))
     f_nv = lambda *a: jnp.sum(jnp.sin(naive(*a)))
-    g1 = jax.grad(f_cv, argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.grad(f_scan, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(f_nv, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         assert _rel_err(a, b) < 1e-4
@@ -379,7 +385,9 @@ def test_flash_attention_rows_sum_to_convex_combination(seed, scale):
     q = scale * jax.random.normal(ks[0], (1, 128, 1, 64), jnp.float32)
     k = scale * jax.random.normal(ks[1], (1, 128, 1, 64), jnp.float32)
     v = jax.random.normal(ks[2], (1, 128, 1, 64), jnp.float32)
-    out = np.asarray(ops.flash_attention(q, k, v, causal=True))
+    out = np.asarray(fa.flash_attention_fwd(q[:, :, 0], k[:, :, 0],
+                                            v[:, :, 0], causal=True,
+                                            interpret=True))
     vmax = float(np.max(v)) + 1e-4
     vmin = float(np.min(v)) - 1e-4
     assert out.max() <= vmax and out.min() >= vmin

@@ -111,8 +111,8 @@ def test_load_anchors_missing_and_broken(tmp_path):
 # ---------------------------------------------------------------------------
 # PodSimulator — progress-based execution
 # ---------------------------------------------------------------------------
-def _sim(frozen=False):
-    return PodSimulator(V5E_POD, frozen=frozen)
+def _sim():
+    return PodSimulator(V5E_POD)
 
 
 def test_single_job_unthrottled_finish():
@@ -153,17 +153,6 @@ def test_pinned_duration_ignores_throttle():
     assert sim.jobs[0].delay_s == pytest.approx(7.0)
 
 
-def test_frozen_mode_matches_legacy_duration_expression():
-    sim = _sim(frozen=True)
-    sim.admit(0, 128, 1.0, 2.0, 10, 0.0)
-    fin = sim.admit(1, 128, 1.0, 2.0, 10, 0.0)
-    loads = [InstanceLoad(128, 1.0, 2.0, 1)] * 2
-    f = throttle_factor(loads, V5E_POD)
-    t_comp = 2.0 * 1.0
-    assert fin == 10 * (t_comp / f + (2.0 - t_comp))  # exact float match
-    assert sim.finish_times(0.0) == {}  # frozen: nothing to re-project
-
-
 def test_resize_preserves_progress_fraction():
     sim = _sim()
     sim.admit(0, 128, 0.5, 2.0, 10, 0.0)   # work_total = 20 nominal seconds
@@ -176,12 +165,7 @@ def test_resize_preserves_progress_fraction():
     assert sim.finish_times(10.0)[0] == pytest.approx(10.0 + 40.0)
 
 
-def test_resize_rebases_frozen_duration_but_not_pinned():
-    frozen = _sim(frozen=True)
-    frozen.admit(0, 128, 0.0, 2.0, 10, 0.0)       # u=0: fixed_s = 20
-    frozen.resize(0, 16, 0.0, 8.0)                # 4× slower steps
-    assert frozen.jobs[0].fixed_s == pytest.approx(80.0)
-    assert frozen.projected_finish(0, 0.0) == pytest.approx(80.0)
+def test_resize_keeps_pinned_duration():
     pinned = _sim()
     pinned.admit(0, 128, 0.5, 2.0, 10, 0.0, duration_s=50.0)
     pinned.resize(0, 16, 0.5, 8.0)
@@ -228,13 +212,22 @@ def test_admit_with_work_done_resumes_progress():
     assert fin == pytest.approx(0.0)
 
 
-def test_admit_fixed_remaining_overrides_frozen_expression():
-    sim = PodSimulator(V5E_POD, frozen=True)
-    fin = sim.admit(0, 64, 0.5, 2.0, 10, 5.0, fixed_remaining=7.0,
-                    start_delay=1.0)
-    assert fin == pytest.approx(13.0)   # t + delay + remaining
-    assert sim.jobs[0].fixed_s == pytest.approx(7.0)
-    assert not sim.jobs[0].pinned       # frozen remainder, not a contract
+def test_preempted_pinned_job_resumes_with_remaining_wall_time():
+    # a checkpoint eviction carries a pinned job's remaining wall time,
+    # which the resume re-admits as duration_s on another mix and profile
+    sim = _sim()
+    sim.admit(0, 128, 1.0, 2.0, 10, 0.0, duration_s=50.0)
+    sim.admit(1, 128, 1.0, 2.0, 10, 0.0)   # a rival pushes f below 1
+    assert sim.throttle() < 1.0
+    sim.advance(20.0)
+    left = sim.remove(0).fixed_s
+    assert left == pytest.approx(30.0)     # wall seconds, never stretched
+    other = _sim()
+    fin = other.admit(0, 16, 0.3, 8.0, 10, 25.0, duration_s=left,
+                      start_delay=2.0)
+    assert fin == pytest.approx(25.0 + 2.0 + 30.0)   # t + restore + left
+    assert other.jobs[0].fixed_s == pytest.approx(30.0)
+    assert 0 not in other.finish_times(25.0)
 
 
 def test_sim_draw_matches_power_model():

@@ -1,17 +1,21 @@
-"""Pinned end-to-end timeline hashes (the PR 6 bit-identity contract).
+"""Pinned end-to-end timelines of the cluster scheduler.
 
 The scale work (free-rectangle index, drain gate, indexed event heap,
-undo-log rollback) is pure mechanism: every showcase and golden trace
-must schedule each job to the exact same (place, finish) float pair as
-before. These hashes were recorded on the pre-optimization tree; any
-drift here means a hot-path rewrite changed a *decision*, not just its
-speed.
+undo-log rollback) is pure mechanism: every showcase and the seeded
+48-job trace must make the same decisions as when the pins were
+recorded. Any drift here means a hot-path rewrite changed a *decision*,
+not just its speed.
 
-``sha(records)`` hashes the repr of ``(job_id, place_s, finish_s)``
-tuples — float repr round-trips exactly, so this pins bit-identical
-times, not approximately equal ones.
+A pin holds, for each job, its decisions exactly — pod, slice profile,
+priced rung, origin, whether it shrank or grew, its preemption and
+migration counts — and the run's action counters exactly. Place and
+finish times are compared within ``TIME_RTOL`` relative: a regrouped
+float sum moves a time by an ulp, a changed decision moves it by far
+more. The pins live in ``tests/data/timeline_pins.json``.
 """
-import hashlib
+import json
+import math
+from pathlib import Path
 
 import pytest
 
@@ -23,95 +27,120 @@ from repro.cluster import (ClusterScheduler, PolicySpec, TraceConfig,
                            search_showcase, twin_showcase)
 from repro.core.hw import MI300_POD
 
+PINS_PATH = Path(__file__).parent / "data" / "timeline_pins.json"
+TIME_RTOL = 1e-9
+ACTION_COUNTERS = ("placed", "completed", "left_queued", "still_running",
+                   "repacks", "repack_failures", "shrinks", "grows",
+                   "preemptions", "resumes", "migrations", "reconfigs",
+                   "power_deferrals")
 
-def sha(records):
-    return hashlib.sha256(
-        repr([(r.job.job_id, r.place_s, r.finish_s)
-              for r in records]).encode()).hexdigest()
+
+def trace0():
+    """The seeded 48-job trace of the trace-0 goldens."""
+    return generate_trace(TraceConfig(seed=0, n_jobs=48,
+                                      mean_interarrival_s=5.0))
 
 
+# name -> (trace factory, ClusterScheduler kwargs)
 SHOWCASE_PINS = {
     "fragmentation": (
         fragmentation_showcase,
-        dict(n_pods=1, horizon_s=3000.0, spec=PolicySpec()),
-        "00d93ed5aab508724410798f6b27023c3fa7139b5ea10b2caf32ad5e9032076e"),
+        dict(n_pods=1, horizon_s=3000.0, spec=PolicySpec())),
     "elastic": (
         elastic_showcase,
         dict(n_pods=1, horizon_s=3000.0,
-             spec=PolicySpec(actions=("shrink",))),
-        "906942ab6d849c5bddd7f43a58d7cfea4f541e9a24395ad08c2e8a4a1cc86945"),
+             spec=PolicySpec(actions=("shrink",)))),
     "preemption": (
         preemption_showcase,
-        dict(n_pods=1, spec=PolicySpec(actions=("shrink", "preempt"))),
-        "658f1c422ca07647d98f23f065fe0f9dff13fc62d725b94ed2f777e2704031be"),
+        dict(n_pods=1, spec=PolicySpec(actions=("shrink", "preempt")))),
     "grow": (
         grow_showcase,
-        dict(n_pods=1, horizon_s=3000.0, spec=PolicySpec(actions=("grow",))),
-        "302fb76d7e1d2e7b9532f1e7a4a622c00fbc9a1441a3b86ee314766b76a1e519"),
+        dict(n_pods=1, horizon_s=3000.0, spec=PolicySpec(actions=("grow",)))),
     "migration": (
         migration_showcase,
         dict(n_pods=2, horizon_s=3000.0,
-             spec=PolicySpec(actions=("shrink", "preempt", "migrate"))),
-        "de8c9377f8eb1f954f646b92a6277ad7e105581b3b6ade00087434d435aead3c"),
+             spec=PolicySpec(actions=("shrink", "preempt", "migrate")))),
     "lookahead": (
         lookahead_showcase,
         dict(n_pods=1, horizon_s=3000.0,
              spec=PolicySpec(selector="lookahead",
-                             actions=("shrink", "preempt"))),
-        "14f2bdc4a3ee504cd6255cc5933d2463bc29c1d191075ee8cecb65cb5cbb0f39"),
+                             actions=("shrink", "preempt")))),
     # PR 8: the three-eviction chain only the best-first search commits
     "search": (
         search_showcase,
         dict(n_pods=1,
              spec=PolicySpec(selector="search",
-                             actions=("shrink", "preempt"))),
-        "3395a68d136691137546a5cfbdb92246181a5a3c52a9a0308b7b3e346af32770"),
+                             actions=("shrink", "preempt")))),
     # PR 9: the twin-offload trace replayed with twin pricing left OFF —
     # the deadline job queues to a miss; the twin-on flip is asserted in
-    # test_twin.py. This pin holds the default-off path bit-identical.
+    # test_twin.py. This pin holds the default-off path.
     "twin-off": (
         twin_showcase,
-        dict(n_pods=1, spec=PolicySpec(actions=("shrink", "preempt"))),
-        "3b829c2d72cd936198d09980e7af53b3ba809aa9e94774ee60bd42c8b148003c"),
+        dict(n_pods=1, spec=PolicySpec(actions=("shrink", "preempt")))),
     # PR 10: the MI300 mode-switch trace replayed with reconfigure OFF —
     # every pod stays pinned in the boot mode (spx-nps1) and the deadline
     # job waits out the tenants to a miss; the reconfigure-on flip is
     # asserted in test_reconfigure.py. This pin holds the mode-less
-    # default path bit-identical.
+    # default path.
     "reconfigure-off": (
         reconfigure_showcase,
         dict(n_pods=2, pod=MI300_POD,
-             spec=PolicySpec(actions=("migrate",))),
-        "391e6faec2fe799cb5a2a93a9b558535857f1fb3daea0acbc6552895147b3ad7"),
+             spec=PolicySpec(actions=("migrate",)))),
 }
+TRACE0_POLICIES = ("frag", "frag_repack")
+PINS = {**{name: (fn, dict(policy="frag_repack", **kw))
+           for name, (fn, kw) in SHOWCASE_PINS.items()},
+        **{f"trace0-{p}": (trace0, dict(n_pods=1, policy=p))
+           for p in TRACE0_POLICIES}}
+
+
+def timeline(records, metrics):
+    """A run's pin: one row per job (decisions, then place/finish times)
+    and the action counters."""
+    jobs = [[r.job.job_id, r.pod_idx, r.profile_name, r.rung,
+             list(r.origin) if r.origin is not None else None,
+             r.shrunk, r.grown, r.preemptions, r.migrations,
+             r.place_s, r.finish_s] for r in records]
+    return {"jobs": jobs,
+            "actions": {k: getattr(metrics, k) for k in ACTION_COUNTERS}}
+
+
+def _close(got, want):
+    if got is None or want is None:
+        return got is want
+    return math.isclose(got, want, rel_tol=TIME_RTOL)
+
+
+def assert_pinned(name, records, metrics):
+    """Decisions and action counters exact, times within ``TIME_RTOL``."""
+    want = json.loads(PINS_PATH.read_text())[name]
+    got = timeline(records, metrics)
+    assert got["actions"] == want["actions"], (
+        f"{name}: action counters drifted — a change altered a decision")
+    assert len(got["jobs"]) == len(want["jobs"]), name
+    for g, w in zip(got["jobs"], want["jobs"]):
+        assert g[:-2] == w[:-2], (
+            f"{name}: job {w[0]} decision drifted: {g[:-2]} != {w[:-2]}")
+        assert _close(g[-2], w[-2]) and _close(g[-1], w[-1]), (
+            f"{name}: job {w[0]} (place, finish) drifted: "
+            f"{g[-2:]} != {w[-2:]}")
+
+
+def run_pin(name):
+    trace_fn, kwargs = PINS[name]
+    sched = ClusterScheduler(**kwargs)
+    records, metrics = sched.run(trace_fn())
+    return sched, records, metrics
 
 
 @pytest.mark.parametrize("name", sorted(SHOWCASE_PINS))
 def test_showcase_timeline_pinned(name):
-    trace_fn, kwargs, expected = SHOWCASE_PINS[name]
-    sched = ClusterScheduler(policy="frag_repack", **kwargs)
-    records, _ = sched.run(trace_fn())
-    assert sha(records) == expected, (
-        f"{name} showcase timeline drifted — a perf change altered a "
-        f"scheduling decision")
+    sched, records, metrics = run_pin(name)
+    assert_pinned(name, records, metrics)
     assert not sched._txns   # every recorded trial was closed
 
 
-# the PR 2/3 goldens: seeded 48-job trace, frozen and progress engines
-TRACE0_PINS = {
-    True: ("429696d0b32a6c03aec769b791fd0683498c4ec9749b15f463820d6b919fb9c8",
-           5841.312618401943),
-    False: ("546680c49ee821980492c3bfbe2af8d65a862bc70edaa9f8e710870db60ce772",
-            5890.25934641167),
-}
-
-
-@pytest.mark.parametrize("frozen", sorted(TRACE0_PINS))
-def test_trace0_timeline_pinned(frozen):
-    expected_sha, expected_makespan = TRACE0_PINS[frozen]
-    jobs = generate_trace(TraceConfig(seed=0, n_jobs=48,
-                                      mean_interarrival_s=5.0))
-    sched = ClusterScheduler(n_pods=1, frozen_durations=frozen)
-    records, metrics = sched.run(jobs)
-    assert sha(records) == expected_sha
-    assert metrics.makespan_s == expected_makespan
+@pytest.mark.parametrize("policy", TRACE0_POLICIES)
+def test_trace0_timeline_pinned(policy):
+    _, records, metrics = run_pin(f"trace0-{policy}")
+    assert_pinned(f"trace0-{policy}", records, metrics)

@@ -19,8 +19,7 @@ Layers covered:
 """
 import pytest
 
-from repro.cluster import ClusterScheduler, PolicySpec, TraceConfig, \
-    generate_trace, twin_showcase
+from repro.cluster import ClusterScheduler, PolicySpec, twin_showcase
 from repro.cluster.trace import SERVING, Job
 from repro.configs import get_config, get_shape
 from repro.core.hw import V5E, V5E_HOST, V5E_HOST_C2C, GiB, HostSpec
@@ -324,25 +323,20 @@ def test_twin_probe_cache_never_collides_rungs():
 # the default-off pin contract, in the same session as the twin modules
 # ---------------------------------------------------------------------------
 def test_trace0_pins_bit_identical_with_twin_models_loaded():
-    """Replaying the PR 2/3 golden AFTER twin-enabled models have been
-    built and scored must still match the frozen sha: the twin machinery
-    lives in separate memo tables and never leaks into default pricing."""
-    from test_timeline_pins import TRACE0_PINS, sha
+    """Replaying the seeded trace-0 pins AFTER twin-enabled models have
+    been built and scored must still match them: the twin machinery lives
+    in separate memo tables and never leaks into default pricing."""
+    from test_timeline_pins import TRACE0_POLICIES, assert_pinned, run_pin
     on = get_model(twin=TWIN)
     on.options(_job())                      # populate twin memo tables
-    jobs = generate_trace(TraceConfig(seed=0, n_jobs=48,
-                                      mean_interarrival_s=5.0))
-    for frozen, (expected_sha, expected_makespan) in TRACE0_PINS.items():
-        sched = ClusterScheduler(n_pods=1, frozen_durations=frozen)
-        records, metrics = sched.run(jobs)
-        assert sha(records) == expected_sha
-        assert metrics.makespan_s == expected_makespan
+    for policy in TRACE0_POLICIES:
+        _, records, metrics = run_pin(f"trace0-{policy}")
+        assert_pinned(f"trace0-{policy}", records, metrics)
 
 
 def test_showcase_pins_bit_identical_with_twin_models_loaded():
-    from test_timeline_pins import SHOWCASE_PINS, sha
+    from test_timeline_pins import SHOWCASE_PINS, assert_pinned, run_pin
     get_model(twin=TWIN).options(_job())    # twin tables live and warm
-    for name, (trace_fn, kwargs, expected) in sorted(SHOWCASE_PINS.items()):
-        sched = ClusterScheduler(policy="frag_repack", **kwargs)
-        records, _ = sched.run(trace_fn())
-        assert sha(records) == expected, f"{name} drifted with twin loaded"
+    for name in sorted(SHOWCASE_PINS):
+        _, records, metrics = run_pin(name)
+        assert_pinned(name, records, metrics)
