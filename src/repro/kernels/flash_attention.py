@@ -24,7 +24,15 @@ running max and sum, ``lse``, ``delta`` and the accumulators stay fp32.
 Causality: a block wholly above the diagonal is skipped, its compute by
 ``pl.when`` and its fetch by index maps that clamp a skipped step to the
 block already resident (the pipeline issues no copy when a block index
-repeats); only blocks crossing the diagonal build the element mask.
+repeats); only blocks crossing the diagonal build the element mask. A
+crossing block of equal q and kv sizes (every one ``pick_block`` gives)
+is cut into bands ``sub_block`` wide where it holds two or more: each
+band runs from the block's edge to the diagonal and is masked whole
+(``_diagonal_bands``), so only the sub-tiles on or below the diagonal are
+computed: a single block of 1024 computes 36 of its 64 sub-tiles of 128.
+One masked band a row of sub-tiles beat separate unmasked sub-tiles on
+the chip: the forward's running max, sum and accumulator are then
+updated once a band (PERF.md, "Findings").
 
 Block sizes come from the shape (``pick_block``): the largest multiple of
 128 dividing S, up to ``MAX_BLOCK``, whose VMEM estimate fits the budget.
@@ -53,6 +61,9 @@ LANES = 128
 # the gpt2-124m training shape (PERF.md, "Findings")
 MAX_BLOCK = 1024
 VMEM_BUDGET = 48 * 2**20     # of the v5e core's 128 MiB VMEM
+# width of the bands a diagonal block is cut into; the chip chose it
+# from 128 and 256 at the gpt2-124m training shape (PERF.md, "Findings")
+SUB_BLOCK = 128
 
 
 def lanes(width: int, head_dim: int) -> int:
@@ -84,7 +95,18 @@ def pick_block(S: int, lanes_: int, itemsize: int) -> int:
     return fits[-1] if fits else min(S, LANES)
 
 
-def _geometry(shape_q, shape_k, dtype, head_dim, block_q, block_k):
+def sub_block(block_q: int, block_k: int, sub=None):
+    """Width of the bands a block crossing the diagonal is cut into
+    (``sub``, default ``SUB_BLOCK``), or None where it is computed whole:
+    blocks of unequal sizes, and blocks that do not hold two or more
+    bands."""
+    sub = sub or SUB_BLOCK
+    if block_q != block_k or block_q % sub or block_q < 2 * sub:
+        return None
+    return sub
+
+
+def _geometry(shape_q, shape_k, dtype, head_dim, block_q, block_k, sub):
     X, S, W = shape_q
     Sk = shape_k[1]
     hd = head_dim or W
@@ -98,7 +120,8 @@ def _geometry(shape_q, shape_k, dtype, head_dim, block_q, block_k):
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=max(32 * 2**20,
                              2 * _vmem_bytes(block_q, block_k, L, itemsize)))
-    return hd, L, W // L, block_q, block_k, params
+    return (hd, L, W // L, block_q, block_k, sub_block(block_q, block_k, sub),
+            params)
 
 
 # ---------------------------------------------------------------------------
@@ -117,18 +140,42 @@ def _q_block_seen(ik, iq, block_q: int, block_k: int):
     return jnp.maximum(iq, (ik * block_k) // block_q)
 
 
-def _run_blocks(step, q_start, k_start, block_q: int, block_k: int,
-                causal: bool):
-    """Run ``step(masked)`` for one (q block, kv block) pair: not at all
-    above the diagonal, with the element mask where the block crosses it,
-    without it below."""
+def _diagonal_bands(block: int, sub: int, *, along_q: bool = False):
+    """The bands of a diagonal block ``sub`` wide, as (q slice, kv slice)
+    from the block's start: each row of sub-tiles from the block's left
+    edge to the diagonal, or with ``along_q`` (the dk/dv kernel, which
+    accumulates per kv row) each column from the diagonal to the bottom
+    edge. Together they hold each sub-tile on or below the diagonal
+    once."""
+    bands = []
+    for lo in range(0, block, sub):
+        tile = slice(lo, lo + sub)
+        bands.append((slice(lo, block), tile) if along_q
+                     else (tile, slice(0, lo + sub)))
+    return bands
+
+
+def _run_blocks(piece, q_start, k_start, block_q: int, block_k: int,
+                causal: bool, sub, *, along_q: bool = False):
+    """Run ``piece(q slice, kv slice, masked)`` over one (q block, kv
+    block) pair: not at all above the diagonal; where the block crosses
+    it, with the element mask, the whole tile or, cut ``sub`` wide, each
+    of ``_diagonal_bands``; below it the whole tile without the mask."""
+    whole = (slice(0, block_q), slice(0, block_k))
     if not causal:
-        step(False)
+        piece(*whole, False)
         return
+
+    def diagonal():
+        bands = ([whole] if sub is None
+                 else _diagonal_bands(block_q, sub, along_q=along_q))
+        for qs, ks in bands:
+            piece(qs, ks, True)
+
     needed = k_start <= q_start + block_q - 1
     crosses = k_start + block_k - 1 > q_start
-    pl.when(needed & crosses)(lambda: step(True))
-    pl.when(needed & jnp.logical_not(crosses))(lambda: step(False))
+    pl.when(needed & crosses)(diagonal)
+    pl.when(needed & jnp.logical_not(crosses))(lambda: piece(*whole, False))
 
 
 def _causal_mask(shape, q_start, k_start, *, q_axis: int):
@@ -203,8 +250,8 @@ def _specs(G: int, L: int):
 # forward
 # ---------------------------------------------------------------------------
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
-                block_k: int, causal: bool, kv_blocks: int, heads: _Heads,
-                stats: bool):
+                block_k: int, sub, causal: bool, kv_blocks: int,
+                heads: _Heads, stats: bool):
     if stats:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -221,26 +268,28 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
     q_start = iq * block_q
     k_start = ik * block_k
 
-    def step(masked: bool):
-        q = _scaled(q_ref[0], scale)                       # (bq, L)
-        k, v = k_ref[0], v_ref[0]                          # (bk, L)
-        mask = (_causal_mask((block_q, block_k), q_start, k_start, q_axis=0)
+    def piece(qs, ks, masked: bool):
+        q = _scaled(q_ref[0, qs], scale)                   # (pq, L)
+        k, v = k_ref[0, ks], v_ref[0, ks]                  # (pk, L)
+        mask = (_causal_mask((q.shape[0], k.shape[0]), q_start + qs.start,
+                             k_start + ks.start, q_axis=0)
                 if masked else None)
         for h in range(heads.n):
-            s = _dot_nt(heads.of(q, h), k)                 # (bq, bk) fp32
+            s = _dot_nt(heads.of(q, h), k)                 # (pq, pk) fp32
             if masked:
                 s = jnp.where(mask, s, NEG_INF)
-            m_prev = m_scr[h]                              # (bq, 1)
+            m_prev = m_scr[h, qs]                          # (pq, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
             corr = jnp.exp(m_prev - m_new)
-            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=1, keepdims=True)
-            acc = acc_scr[...]
-            acc_scr[...] = (heads.put(acc, h, acc * corr)
-                            + _dot(p.astype(v.dtype), heads.of(v, h)))
-            m_scr[h] = m_new
+            l_scr[h, qs] = (l_scr[h, qs] * corr
+                            + jnp.sum(p, axis=1, keepdims=True))
+            acc = acc_scr[qs]
+            acc_scr[qs] = (heads.put(acc, h, acc * corr)
+                           + _dot(p.astype(v.dtype), heads.of(v, h)))
+            m_scr[h, qs] = m_new
 
-    _run_blocks(step, q_start, k_start, block_q, block_k, causal)
+    _run_blocks(piece, q_start, k_start, block_q, block_k, causal, sub)
 
     @pl.when(ik == kv_blocks - 1)
     def _finish():
@@ -254,12 +303,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale: float, block_q: int,
         o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _fwd_call(q, k, v, *, causal, scale, head_dim, block_q, block_k,
+def _fwd_call(q, k, v, *, causal, scale, head_dim, block_q, block_k, sub,
               interpret, stats: bool):
     X, S, W = q.shape
     Sk = k.shape[1]
-    hd, L, G, block_q, block_k, params = _geometry(
-        q.shape, k.shape, q.dtype, head_dim, block_q, block_k)
+    hd, L, G, block_q, block_k, sub, params = _geometry(
+        q.shape, k.shape, q.dtype, head_dim, block_q, block_k, sub)
     n = L // hd
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     block, row = _specs(G, L)
@@ -272,7 +321,7 @@ def _fwd_call(q, k, v, *, causal, scale, head_dim, block_q, block_k,
         out_shape.append(jax.ShapeDtypeStruct((X * G, n, S), jnp.float32))
     kernel = functools.partial(
         _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
-        causal=causal, kv_blocks=Sk // block_k, heads=_Heads(n, hd),
+        sub=sub, causal=causal, kv_blocks=Sk // block_k, heads=_Heads(n, hd),
         stats=stats)
     out = pl.pallas_call(
         kernel,
@@ -292,25 +341,26 @@ def _fwd_call(q, k, v, *, causal, scale, head_dim, block_q, block_k,
 
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, scale=None,
-                        head_dim=None, block_q=None, block_k=None,
+                        head_dim=None, block_q=None, block_k=None, sub=None,
                         interpret: bool = False):
     """q, k, v: (X, S, W), heads ``head_dim`` wide side by side along W
     (default: one head, W). Blocks default to ``pick_block`` of the
-    shape; scale to ``head_dim ** -0.5``."""
+    shape, the bands of a diagonal block to ``sub_block``; scale to
+    ``head_dim ** -0.5``."""
     return _fwd_call(q, k, v, causal=causal, scale=scale, head_dim=head_dim,
-                     block_q=block_q, block_k=block_k, interpret=interpret,
-                     stats=False)
+                     block_q=block_q, block_k=block_k, sub=sub,
+                     interpret=interpret, stats=False)
 
 
 def flash_attention_fwd_stats(q, k, v, *, causal: bool = True, scale=None,
                               head_dim=None, block_q=None, block_k=None,
-                              interpret: bool = False):
+                              sub=None, interpret: bool = False):
     """Forward + logsumexp stats (for the backward kernels).
     Returns (out (X, S, W), lse (X * G, n, S) fp32): per lane group of n
     heads, one lane-dense row per head, so a block of them is (n, block_q)."""
     return _fwd_call(q, k, v, causal=causal, scale=scale, head_dim=head_dim,
-                     block_q=block_q, block_k=block_k, interpret=interpret,
-                     stats=True)
+                     block_q=block_q, block_k=block_k, sub=sub,
+                     interpret=interpret, stats=True)
 
 
 # ---------------------------------------------------------------------------
@@ -318,7 +368,8 @@ def flash_attention_fwd_stats(q, k, v, *, causal: bool = True, scale=None,
 # ---------------------------------------------------------------------------
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float, block_q: int,
-                block_k: int, causal: bool, q_blocks: int, heads: _Heads):
+                block_k: int, sub, causal: bool, q_blocks: int,
+                heads: _Heads):
     """dk, dv for one kv block, accumulated over the q blocks (innermost).
     Works on transposed tiles, (block_k, block_q), so lse and delta enter
     as rows and every matmul is a plain or an rhs-transposed one."""
@@ -333,22 +384,24 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_start = iq * block_q
     k_start = ik * block_k
 
-    def step(masked: bool):
-        q = _scaled(q_ref[0], scale)                      # (bq, L)
-        k, v, do = k_ref[0], v_ref[0], do_ref[0]
-        mask = (_causal_mask((block_k, block_q), q_start, k_start, q_axis=1)
+    def piece(qs, ks, masked: bool):
+        q = _scaled(q_ref[0, qs], scale)                  # (pq, L)
+        k, v, do = k_ref[0, ks], v_ref[0, ks], do_ref[0, qs]
+        mask = (_causal_mask((k.shape[0], q.shape[0]), q_start + qs.start,
+                             k_start + ks.start, q_axis=1)
                 if masked else None)
         for h in range(heads.n):
-            st = _dot_nt(heads.of(k, h), q)               # (bk, bq) fp32
+            st = _dot_nt(heads.of(k, h), q)               # (pk, pq) fp32
             if masked:
                 st = jnp.where(mask, st, NEG_INF)
-            pt = jnp.exp(st - lse_ref[0, h:h + 1, :])     # lse: (1, bq)
-            dv_scr[...] += _dot(pt.astype(do.dtype), heads.of(do, h))
-            dpt = _dot_nt(heads.of(v, h), do)             # (bk, bq)
-            dst = pt * (dpt - delta_ref[0, h:h + 1, :])
-            dk_scr[...] += _dot(dst.astype(q.dtype), heads.of(q, h))
+            pt = jnp.exp(st - lse_ref[0, h:h + 1, qs])    # lse: (1, pq)
+            dv_scr[ks] += _dot(pt.astype(do.dtype), heads.of(do, h))
+            dpt = _dot_nt(heads.of(v, h), do)             # (pk, pq)
+            dst = pt * (dpt - delta_ref[0, h:h + 1, qs])
+            dk_scr[ks] += _dot(dst.astype(q.dtype), heads.of(q, h))
 
-    _run_blocks(step, q_start, k_start, block_q, block_k, causal)
+    _run_blocks(piece, q_start, k_start, block_q, block_k, causal, sub,
+                along_q=True)
 
     @pl.when(iq == q_blocks - 1)
     def _finish():
@@ -358,7 +411,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_scr, lse_scr, delta_scr, *, scale: float, block_q: int,
-               block_k: int, causal: bool, kv_blocks: int, heads: _Heads):
+               block_k: int, sub, causal: bool, kv_blocks: int,
+               heads: _Heads):
     """dq for one q block, accumulated over the kv blocks (innermost)."""
     iq = pl.program_id(1)
     ik = pl.program_id(2)
@@ -373,21 +427,22 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     q_start = iq * block_q
     k_start = ik * block_k
 
-    def step(masked: bool):
-        q = _scaled(q_ref[0], scale)
-        k, v, do = k_ref[0], v_ref[0], do_ref[0]
-        mask = (_causal_mask((block_q, block_k), q_start, k_start, q_axis=0)
+    def piece(qs, ks, masked: bool):
+        q = _scaled(q_ref[0, qs], scale)
+        k, v, do = k_ref[0, ks], v_ref[0, ks], do_ref[0, qs]
+        mask = (_causal_mask((q.shape[0], k.shape[0]), q_start + qs.start,
+                             k_start + ks.start, q_axis=0)
                 if masked else None)
         for h in range(heads.n):
-            s = _dot_nt(heads.of(q, h), k)                # (bq, bk) fp32
+            s = _dot_nt(heads.of(q, h), k)                # (pq, pk) fp32
             if masked:
                 s = jnp.where(mask, s, NEG_INF)
-            p = jnp.exp(s - lse_scr[h])
+            p = jnp.exp(s - lse_scr[h, qs])
             dp = _dot_nt(heads.of(do, h), v)
-            ds = p * (dp - delta_scr[h])
-            dq_scr[...] += _dot(ds.astype(k.dtype), heads.of(k, h))
+            ds = p * (dp - delta_scr[h, qs])
+            dq_scr[qs] += _dot(ds.astype(k.dtype), heads.of(k, h))
 
-    _run_blocks(step, q_start, k_start, block_q, block_k, causal)
+    _run_blocks(piece, q_start, k_start, block_q, block_k, causal, sub)
 
     @pl.when(ik == kv_blocks - 1)
     def _finish():
@@ -396,14 +451,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def flash_attention_bwd(q, k, v, out, lse, dout, *, causal: bool = True,
                         scale=None, head_dim=None, block_q=None, block_k=None,
-                        interpret: bool = False):
+                        sub=None, interpret: bool = False):
     """Flash backward: (dq, dk, dv), each shaped as q, k, v. ``lse`` from
     flash_attention_fwd_stats. Two pallas_calls: dk/dv with the q dim
     innermost, dq with the kv dim innermost, each accumulating in VMEM."""
     X, S, W = q.shape
     Sk = k.shape[1]
-    hd, L, G, block_q, block_k, params = _geometry(
-        q.shape, k.shape, q.dtype, head_dim, block_q, block_k)
+    hd, L, G, block_q, block_k, sub, params = _geometry(
+        q.shape, k.shape, q.dtype, head_dim, block_q, block_k, sub)
     n = L // hd
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     delta = jnp.sum((dout.astype(jnp.float32) * out.astype(jnp.float32))
@@ -421,7 +476,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal: bool = True,
     q_blk, k_blk = block(block_q, qi), block(block_k, lambda ik, iq: ik)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, causal=causal,
+                          block_k=block_k, sub=sub, causal=causal,
                           q_blocks=S // block_q, heads=heads),
         grid=(X * G, Sk // block_k, S // block_q),
         in_specs=[q_blk, k_blk, k_blk, q_blk, row(block_q, n, qi),
@@ -440,7 +495,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal: bool = True,
     q_row = row(block_q, n, lambda iq, ik: iq)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, causal=causal,
+                          block_k=block_k, sub=sub, causal=causal,
                           kv_blocks=Sk // block_k, heads=heads),
         grid=(X * G, S // block_q, Sk // block_k),
         in_specs=[q_blk, k_blk, k_blk, q_blk, q_row, q_row],

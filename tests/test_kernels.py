@@ -23,20 +23,35 @@ def _rel_err(got, want):
 # flash attention
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,S,H,hd,bq,bk", [
-    (1, 128, 2, 64, 128, 128),
-    (2, 256, 4, 64, 128, 128),
-    (1, 256, 1, 128, 64, 128),
-    (2, 512, 2, 32, 128, 256),
+@pytest.mark.parametrize("B,S,H,hd,bq,bk,sub", [
+    pytest.param(1, 128, 2, 64, 128, 128, None, id="1-128-2-64-128-128"),
+    pytest.param(2, 256, 4, 64, 128, 128, None, id="2-256-4-64-128-128"),
+    pytest.param(1, 256, 1, 128, 64, 128, None, id="1-256-1-128-64-128"),
+    pytest.param(2, 512, 2, 32, 128, 256, None, id="2-512-2-32-128-256"),
+    # diagonal blocks cut into bands, two heads of 64 read in place: two
+    # blocks of 256 (one on the diagonal, one below it), and the one
+    # block of 1024 of gpt2's training rows
+    pytest.param(1, 512, 2, 64, 256, 256, 128, id="in-place-512-256-sub-128"),
+    pytest.param(1, 1024, 2, 64, 1024, 1024, 256,
+                 id="in-place-1024-1024-sub-256"),
+    pytest.param(1, 1024, 2, 64, 1024, 1024, 128,
+                 id="in-place-1024-1024-sub-128"),
 ])
-def test_flash_attention_matches_ref(B, S, H, hd, bq, bk, dtype):
+def test_flash_attention_matches_ref(B, S, H, hd, bq, bk, sub, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, S, H, hd), dtype)
     k = jax.random.normal(ks[1], (B, S, H, hd), dtype)
     v = jax.random.normal(ks[2], (B, S, H, hd), dtype)
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-    got = fa.flash_attention_fwd(fold(q), fold(k), fold(v), causal=True,
-                                 block_q=bq, block_k=bk, interpret=True)
+    if sub is None:
+        got = fa.flash_attention_fwd(fold(q), fold(k), fold(v), causal=True,
+                                     block_q=bq, block_k=bk, interpret=True)
+    else:
+        assert fa.sub_block(bq, bk, sub) == sub
+        flat = lambda t: t.reshape(B, S, H * hd)
+        got = fold(fa.flash_attention_fwd(
+            flat(q), flat(k), flat(v), causal=True, head_dim=hd, block_q=bq,
+            block_k=bk, sub=sub, interpret=True).reshape(B, S, H, hd))
     want = ref.attention_ref(fold(q), fold(k), fold(v), causal=True)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     assert _rel_err(got, want) < tol
@@ -70,42 +85,50 @@ def _naive_attention(q, k, v, causal: bool):
 _BLOCK = fa.pick_block(1 << 14, 128, 2)
 
 
-@pytest.mark.parametrize("causal,bq,bk,n_blocks,dtype,H", [
-    pytest.param(True, 64, 64, None, jnp.float32, 1, id="True-64-64"),
-    pytest.param(True, 128, 64, None, jnp.float32, 1, id="True-128-64"),
-    pytest.param(False, 64, 128, None, jnp.float32, 1, id="False-64-128"),
-    *[pytest.param(True, None, None, n, dt, 2,
+@pytest.mark.parametrize("causal,bq,bk,sub,n_blocks,dtype,H", [
+    pytest.param(True, 64, 64, None, None, jnp.float32, 1, id="True-64-64"),
+    pytest.param(True, 128, 64, None, None, jnp.float32, 1,
+                 id="True-128-64"),
+    pytest.param(False, 64, 128, None, None, jnp.float32, 1,
+                 id="False-64-128"),
+    *[pytest.param(True, None, None, None, n, dt, 2,
                    id=f"wrapper-{n}-blocks-{jnp.dtype(dt).name}")
       for dt in (jnp.float32, jnp.bfloat16) for n in (1, 2, 4)],
-    pytest.param(True, None, None, 2, jnp.float32, 3,
+    pytest.param(True, None, None, None, 2, jnp.float32, 3,
                  id="wrapper-2-blocks-float32-folded"),
+    *[pytest.param(True, b, b, sub, n, dt, 2,
+                   id=f"sub-{sub}-of-{n}-blocks-{b}-{jnp.dtype(dt).name}")
+      for dt in (jnp.float32, jnp.bfloat16)
+      for b, sub, n in ((256, 128, 2), (1024, 256, 1))],
 ])
-def test_flash_backward_kernel_matches_autodiff(causal, bq, bk, n_blocks,
-                                                dtype, H):
+def test_flash_backward_kernel_matches_autodiff(causal, bq, bk, sub,
+                                                n_blocks, dtype, H):
     """The Pallas dq/dk/dv kernels against jax.vjp of naive attention: the
-    kernels at explicit blocks in the folded (BH, S, hd) layout, and
-    jax.grad through the custom-VJP wrapper ``causal_flash_attention`` in
-    the model's (B, S, H, hd) layout at the blocks it picks, S at 1, 2 and
-    4 of them: two heads of 64 read in place as one 128-lane block, and
-    three, whose 192 lanes the wrapper folds into the batch."""
+    kernels at explicit blocks, one head of 64 over 4 rows of 256 and,
+    with diagonal blocks cut into bands, two heads of 64 read in place
+    as one 128-lane block; and jax.grad through the custom-VJP wrapper
+    ``causal_flash_attention`` in the model's (B, S, H, hd) layout at the
+    blocks it picks, S at 1, 2 and 4 of them: two heads of 64 read in
+    place, and three, whose 192 lanes the wrapper folds into the batch."""
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
-    if n_blocks is None:      # the kernels: 4 folded heads of 256
-        B, S, hd = 4, 256, 64
+    wrapper = bq is None
+    if not wrapper:
+        B, S, hd = (4, 256, 64) if n_blocks is None else (1, n_blocks * bq,
+                                                          64)
+        assert fa.sub_block(bq, bk, sub) == sub
     else:
         B, S, hd = 1, n_blocks * _BLOCK, 64
         assert ops._in_place((B, S, H, hd)) == (H == 2)
         assert fa.pick_block(S, 128, jnp.dtype(dtype).itemsize) == _BLOCK
     q, k, v = (jax.random.normal(kk, (B, S, H, hd), dtype) for kk in ks[:3])
     do = jax.random.normal(ks[3], (B, S, H, hd), jnp.float32)
-    if n_blocks is None:
-        q1, k1, v1, do1 = (t[:, :, 0] for t in (q, k, v, do))  # H = 1
-        out, lse = fa.flash_attention_fwd_stats(
-            q1, k1, v1, causal=causal, block_q=bq, block_k=bk,
-            interpret=True)
-        grads = fa.flash_attention_bwd(
-            q1, k1, v1, out, lse, do1, causal=causal, block_q=bq,
-            block_k=bk, interpret=True)
-        got = [t[:, :, None] for t in (out, *grads)]
+    if not wrapper:
+        flat = [t.reshape(B, S, H * hd) for t in (q, k, v, do)]
+        kw = dict(causal=causal, head_dim=hd, block_q=bq, block_k=bk,
+                  sub=sub, interpret=True)
+        out, lse = fa.flash_attention_fwd_stats(*flat[:3], **kw)
+        grads = fa.flash_attention_bwd(*flat[:3], out, lse, flat[3], **kw)
+        got = [t.reshape(B, S, H, hd) for t in (out, *grads)]
     else:
         loss = lambda q, k, v: jnp.sum(  # noqa: E731
             ops.causal_flash_attention(q, k, v).astype(jnp.float32) * do)
@@ -117,6 +140,40 @@ def test_flash_backward_kernel_matches_autodiff(causal, bq, bk, n_blocks,
     for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
         assert a.dtype == dtype, name
         assert _rel_err(a, b) < tol, name
+
+
+@pytest.mark.parametrize("along_q", [False, True])
+@pytest.mark.parametrize("sub", [256, 128])
+def test_diagonal_block_plan(sub, along_q):
+    """A diagonal block of 1024 cut into bands of 256 (128) computes each
+    of the 10 (36) sub-tile pairs on or below the diagonal once, in 4 (8)
+    bands, each ending at (with ``along_q``, starting from) its diagonal
+    sub-tile; a block under two bands, or of unequal q and kv sizes, is
+    not cut."""
+    n = 1024 // sub
+    pairs = []
+    bands = fa._diagonal_bands(1024, sub, along_q=along_q)
+    assert len(bands) == n
+    for qs, ks in bands:
+        assert qs.start % sub == qs.stop % sub == ks.start % sub == 0
+        assert ks.stop % sub == 0
+        band = [(qi, ki) for qi in range(qs.start // sub, qs.stop // sub)
+                for ki in range(ks.start // sub, ks.stop // sub)]
+        diag = [(qi, ki) for qi, ki in band if qi == ki]
+        assert len(diag) == 1
+        assert diag[0] == (band[0] if along_q else band[-1])
+        pairs += band
+    assert sorted(pairs) == [(qi, ki) for qi in range(n)
+                             for ki in range(qi + 1)]
+    assert len(pairs) == n * (n + 1) // 2
+    assert fa.sub_block(1024, 1024, 256) == 256
+    assert fa.sub_block(512, 512, 128) == 128
+    assert fa.sub_block(1024, 1024) == fa.SUB_BLOCK
+    for b in (128, 256):
+        assert fa.sub_block(b, b, 256) is None
+    assert fa.sub_block(128, 128) is None
+    assert fa.sub_block(256, 128, 128) is None
+    assert fa.sub_block(1024, 512, 256) is None
 
 
 @pytest.mark.parametrize("bq,bk", [(64, 64), (128, 64), (64, 128)])
@@ -151,6 +208,42 @@ def test_causal_clamp_leaves_results_unchanged(bq, bk, monkeypatch):
     monkeypatch.setattr(fa, "_q_block_seen", lambda ik, iq, bq, bk: iq)
     for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), clamped, run()):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+
+
+@pytest.mark.parametrize("cell,config,rows,seq", [
+    ("gpt2-124m.train", "gpt2-124m", 80, 1024),
+    ("granite-moe-1b.train4k", "granite-moe-1b-a400m", 4, 4096),
+])
+def test_attention_roofline_reader(cell, config, rows, seq):
+    """``attn.roofline.train`` puts the causal attention operations of a
+    step (3 x 4 layers H hd S(S+1)/2 a row) over the time of the ops that
+    ``attn.kernel_ms.train`` reads, at 197 TFLOP/s, and reads nothing
+    where the program has no such kernels."""
+    from types import SimpleNamespace
+    from benchmarks.chip import run
+    cfg = run.read_json(run.HERE / "configs" / f"{config}.json")
+    ref_mod = run.load_module(run.HERE / "reference" / f"{cfg['reference']}.py")
+    spec = ref_mod.spec(cfg)
+    ops_ = {"%flash_attention_bwd_dkv.10 = (bf16[80,1024,768]) ...": 0.9,
+            "%flash_attention_fwd_stats.14 = (bf16[80,1024,768]) ...": 0.8,
+            "%fusion.3 = bf16[80,1024,768]{2,1,0} fusion(...)": 3.0}
+    trace = SimpleNamespace(devices=[SimpleNamespace(ops=ops_)],
+                            program=lambda fn: (10.0, 10))
+    rec = SimpleNamespace(trace=trace, data={"tokens_per_step": rows * seq,
+                                             "seq": seq})
+    ctx = SimpleNamespace(spec=spec, peaks={"bf16_flops_per_s": 197e12})
+    roof = run.load_module(run.HERE / "metrics" / "attn.roofline.train.py")
+    flops = (3 * rows * 4 * spec.layers * spec.heads * spec.head_dim
+             * seq * (seq + 1) / 2)
+    assert roof.read(rec, ctx) == pytest.approx(
+        100 * flops / (1e-3 * 170.0 * 197e12))
+    # the step's causal attention as PERF.md counts it: 4.64 and 3.30 TFLOP
+    assert flops == pytest.approx({"gpt2-124m.train": 4.64e12,
+                                   "granite-moe-1b.train4k": 3.30e12}[cell],
+                                  rel=2e-3)
+    rec.trace = SimpleNamespace(devices=[SimpleNamespace(ops={})],
+                                program=lambda fn: (10.0, 10))
+    assert roof.read(rec, ctx) is None
 
 
 def _attention_inputs(B, Sq, Sk, H, hd, seed=11):

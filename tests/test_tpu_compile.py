@@ -90,11 +90,23 @@ def test_flash_attention_compiles_at_gpt2_training_shape(one_chip, block,
                                                         monkeypatch):
     """Forward with stats and backward at gpt2-124m's training shape, 80
     rows of 1024 positions, 12 heads of 64 in place (blocks of two heads'
-    128 lanes), at each block the shape may be given."""
+    128 lanes), at each block the shape may be given; a diagonal block
+    that holds two or more bands is cut into them."""
     monkeypatch.setattr(fa, "MAX_BLOCK", block)
     assert fa.lanes(768, 64) == 128 and fa.pick_block(1024, 128, 2) == block
+    assert (fa.sub_block(block, block) is None) == (block < 2 * fa.SUB_BLOCK)
     _compile_kernel(lambda *a: _fwd_bwd(*a, head_dim=64),
                     _shapes(one_chip, *[(80, 1024, 768)] * 4))
+
+
+def test_flash_attention_compiles_at_granite_training_shape(one_chip):
+    """Forward with stats and backward at granite-moe-1b-a400m's training
+    shape, 4 rows of 4096 positions, 16 heads of 64 in place: blocks of
+    1024, four a side, the diagonal ones cut into bands."""
+    assert fa.pick_block(4096, 128, 2) == 1024
+    assert fa.sub_block(1024, 1024) == fa.SUB_BLOCK
+    _compile_kernel(lambda *a: _fwd_bwd(*a, head_dim=64),
+                    _shapes(one_chip, *[(4, 4096, 1024)] * 4))
 
 
 def test_gpt2_train_step_fused_attention_fits_one_chip(one_chip,
